@@ -94,7 +94,7 @@ def _slope(ns, bys) -> float:
 
 
 def run(d: int = 16, n_values=(128, 256, 512, 1024), heads: int = 2):
-    interp = jax.default_backend() != "tpu"
+    interp = ops.interpret_mode()
 
     def loss_ref(q, k, v):
         return jnp.sum(T.direct_taylorshift(q, k, v) ** 2)

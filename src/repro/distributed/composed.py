@@ -21,7 +21,7 @@ composes the three proven parts into one measured training path:
     reduce-scatter, so data-axis gradient reduction costs nothing extra.
 
 Everything lives in ONE fully-manual `shard_map` region
-(``check_rep=False``) with `jax.value_and_grad` *inside* the body.
+(``check_vma=False``) with `jax.value_and_grad` *inside* the body.
 Rationale: nesting the existing mesh-level shard_map wrappers
 (`seqscan.make_seq_scan`, `pipeline.pipeline_forward`) is impossible
 (shard_map does not nest), and `auto` mode next to manual axes trips an
@@ -53,7 +53,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import taylor as T
@@ -287,11 +286,11 @@ def build_composed_grad_fn(cfg, mesh, *, global_batch: int, seq_len: int,
         return loss, g_outer, g_stages
 
     batch_specs = {"tokens": P("data", "seq"), "labels": P("data", "seq")}
-    fn = shard_map(
-        body, mesh,
+    fn = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(specs["outer"], specs["stages"], batch_specs),
         out_specs=(P(), specs["outer"], specs["stages"]),
-        check_rep=False)
+        check_vma=False)
 
     def grad_fn(split, batch):
         loss, g_outer, g_stages = fn(split["outer"], split["stages"], batch)
@@ -405,8 +404,9 @@ def measure_seq_exchange(mesh, *, d: int, heads: int = 1,
             hop *= 2
         return tuple(jax.lax.psum(x, seq_axis) for x in st)
 
-    f = jax.jit(shard_map(body, mesh, in_specs=(P(), P(), P()),
-                          out_specs=(P(), P(), P()), check_rep=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(), P(), P()),
+                              out_specs=(P(), P(), P()),
+                              check_vma=False))
     jax.block_until_ready(f(*state))            # compile + warm
     t0 = _time.perf_counter()
     out = None

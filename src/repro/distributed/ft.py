@@ -29,6 +29,7 @@ import time
 from dataclasses import dataclass, field
 
 import jax
+from jax.sharding import AxisType
 
 from repro.obs import metrics as OM
 
@@ -225,5 +226,7 @@ def elastic_remesh(n_devices: int | None = None, model_parallel: int = 1):
     n = n_devices if n_devices is not None else len(devs)
     n = min(n, len(devs))
     data = max(n // model_parallel, 1)
+    # Auto axes, as launch/mesh.py: the train step runs under this mesh
     return jax.make_mesh((data, model_parallel), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
                          devices=devs[:data * model_parallel])

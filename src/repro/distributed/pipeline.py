@@ -18,7 +18,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -76,8 +75,7 @@ def pipeline_forward(stage_fn: Callable, params_stacked, x,
         # params: (1, ...) local stage slice; mb: (M, b, ...) replicated
         stage = jax.lax.axis_index("stage")
         p_local = jax.tree.map(lambda a: a[0], params)
-        # check_rep=False: no replication annotations needed (pvary is
-        # not available on this jax version)
+        # check_vma=False: the ring carries no varying-axis annotations
         buf = jnp.zeros_like(mb[0])
         outs = jnp.zeros_like(mb)
         T = M + S - 1
@@ -106,11 +104,11 @@ def pipeline_forward(stage_fn: Callable, params_stacked, x,
             jnp.where(stage == S - 1, outs, jnp.zeros_like(outs)), "stage")
         return outs
 
-    shmap = shard_map(
-        body, mesh,
+    shmap = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P("stage"), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     y = shmap(params_stacked, mb)
     return y.reshape(-1, *y.shape[2:])[:n_keep]

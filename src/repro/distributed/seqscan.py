@@ -32,7 +32,6 @@ from __future__ import annotations
 import functools
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import taylor as T
@@ -62,8 +61,8 @@ def _wrap(mesh, axis, body, n_sharded_in, n_rep_in, n_sharded_out,
         + tuple(P() for _ in range(n_rep_in))
     out_specs = tuple([_seq_spec(arrs[0].ndim, axis)] * n_sharded_out
                       + [P()] * n_rep_out)
-    return jax.jit(shard_map(body, mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))

@@ -3,9 +3,9 @@
 These are the entry points the attention layer uses when
 ``cfg.taylor.use_kernel`` is set: they apply Algorithm 1's input
 normalization (ℓ2 + temperature τ + α-scaling) in plain JAX, reshape
-(B, H, N, d) → (BH, N, d), and dispatch to the kernels. On non-TPU
-backends they run the kernels in interpret mode (Python execution of the
-kernel body) so correctness is testable anywhere.
+(B, H, N, d) → (BH, N, d), and dispatch to the kernels. On the CPU they
+run the kernels in interpret mode (Python execution of the kernel body)
+so correctness is testable there; see :func:`interpret_mode`.
 """
 
 from __future__ import annotations
@@ -22,8 +22,21 @@ from repro.kernels.taylor_grad import (taylor_direct_attention_vjp,
                                        taylor_efficient_attention_vjp)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run interpreted on this backend.
+
+    The one place that decides it: compiled on a TPU, interpreted on the
+    CPU (tests and CPU rehearsals). Any other platform has no lowering
+    for these kernels, and interpreting them there would hide the
+    device, so it raises.
+    """
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas TPU kernels cannot run on platform {platform!r}")
 
 
 def _flatten_heads(x):
@@ -47,7 +60,7 @@ def taylor_attention_kernel(q, k, v, *, tau=1.0, causal: bool = False,
     mode: auto → paper crossover N0(d); causal currently implies the
     direct kernel (the chunked-causal efficient form stays in core/).
     """
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = interpret_mode() if interpret is None else interpret
     b, h, n, d = q.shape
     m = k.shape[2]
     if mode == "auto":
@@ -96,6 +109,6 @@ def _pad_rows(x, n_pad: int):
     return jnp.pad(x, ((0, 0), (0, n_pad - n), (0, 0)))
 
 
-__all__ = ["taylor_attention_kernel", "taylor_direct_attention",
-           "taylor_efficient_attention", "taylor_direct_attention_vjp",
-           "taylor_efficient_attention_vjp"]
+__all__ = ["interpret_mode", "taylor_attention_kernel",
+           "taylor_direct_attention", "taylor_efficient_attention",
+           "taylor_direct_attention_vjp", "taylor_efficient_attention_vjp"]

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 from repro.core import taylor as T
 from repro.kernels.taylor_efficient import _pick_chunk_factor
 
@@ -92,7 +90,7 @@ def taylor_decode_kernel(state: T.TaylorState, q, k, v, *, tau=1.0,
             jax.ShapeDtypeStruct((bh, d * d, d + 1), jnp.float32),
             jax.ShapeDtypeStruct((bh, nchunks, d + 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(qs, qs, ks, ks, vh, state.s2)

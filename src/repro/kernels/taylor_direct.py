@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, acc_nom, acc_den, *,
             alpha: float, causal: bool, block_q: int, block_k: int,
@@ -139,7 +137,7 @@ def taylor_direct_attention(q, k, v, *, causal: bool = False,
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

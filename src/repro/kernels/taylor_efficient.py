@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 
 def _pick_chunk_factor(d: int, vmem_budget: int = 8 * 1024 * 1024) -> int:
     """How many d-row groups of A_mod to hold per VMEM tile."""
@@ -87,7 +85,7 @@ def _amod_call(k, vh, *, cf: int, block_k: int, interpret: bool):
         out_specs=pl.BlockSpec((1, cf * d, d + 1), lambda b, c, j: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, d * d, d + 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((cf * d, d + 1), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(k, k, vh)
@@ -161,7 +159,7 @@ def _readout_call(q, a_mod, kv, s0, *, cf: int, block_q: int, n_keys: int,
         out_specs=pl.BlockSpec((1, block_q, d_out), lambda b, i, c: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, n, d_out), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d + 1), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, q, a_mod, kv, s0)

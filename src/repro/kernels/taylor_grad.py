@@ -46,7 +46,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 from repro.kernels.taylor_direct import taylor_direct_attention
 from repro.kernels.taylor_efficient import (_amod_call, _pick_chunk_factor,
                                             _readout_call, build_vhat)
@@ -167,7 +166,7 @@ def _direct_bwd_call(q, k, v, gaux, *, causal: bool, block_q: int,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, n, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, gaux, k, v)
@@ -191,7 +190,7 @@ def _direct_bwd_call(q, k, v, gaux, *, causal: bool, block_q: int,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(k, v, q, gaux)
@@ -270,11 +269,11 @@ def _sym_quad_kernel(x_ref, xc_ref, u_ref, a_ref, o1_ref, o2_ref, acc, *,
     """out_i = (M_i + M_iᵀ) x_i with M_i = mat(A u_i), streamed over cf·d
     row-chunks of A so the (N, d²) intermediate never leaves VMEM.
 
-    Chunk c holds A rows π(a, b) for a ∈ [c·cf, (c+1)·cf):
-      t = u A_cᵀ reshaped (bq, cf, d) is M_i restricted to those rows, so
-      o1 (the M x term) lands directly in output columns c·cf:(c+1)·cf,
-      while the Mᵀ x term needs x's *own* chunk columns and accumulates
-      over chunks into o2.
+    Chunk c holds A rows π(a, b) for a ∈ [c·cf, (c+1)·cf). For each row
+    group a, t = u A_aᵀ (bq, d) is row a of M_i, so o1 (the M x term)
+    lands directly in output column a of this chunk, while the Mᵀ x term
+    needs x's *own* column a and accumulates over chunks into o2. Every
+    value stays 2-D: Mosaic refuses a reshape that splits the lane dim.
     """
     ic = pl.program_id(2)
     nc = pl.num_programs(2)
@@ -286,12 +285,22 @@ def _sym_quad_kernel(x_ref, xc_ref, u_ref, a_ref, o1_ref, o2_ref, acc, *,
     x = x_ref[0].astype(jnp.float32)                     # (bq, d)
     xc = xc_ref[0].astype(jnp.float32)                   # (bq, cf)
     u = u_ref[0].astype(jnp.float32)                     # (bq, d+1)
-    a = a_ref[0]                                         # (cf·d, d+1) fp32
-    t = jax.lax.dot_general(u, a, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    t = t.reshape(x.shape[0], cf, d)
-    o1_ref[0] = jnp.sum(t * x[:, None, :], axis=2).astype(o1_ref.dtype)
-    acc[...] += jnp.sum(t * xc[:, :, None], axis=1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, xc.shape, 1)
+
+    def row_group(a, carry):
+        o1, o2 = carry
+        rows = a_ref[0, pl.ds(pl.multiple_of(a * d, d), d), :]  # (d, d+1)
+        t = jax.lax.dot_general(u, rows, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        hit = lane == a
+        o1 = jnp.where(hit, jnp.sum(t * x, axis=1, keepdims=True), o1)
+        xa = jnp.sum(jnp.where(hit, xc, 0.0), axis=1, keepdims=True)
+        return o1, o2 + t * xa
+
+    o1, o2 = jax.lax.fori_loop(
+        0, cf, row_group, (jnp.zeros_like(xc), jnp.zeros_like(x)))
+    o1_ref[0] = o1.astype(o1_ref.dtype)
+    acc[...] += o2
 
     @pl.when(ic == nc - 1)
     def _finish():
@@ -320,7 +329,7 @@ def _sym_quad_call(x, u, a_mod, *, cf: int, block_q: int, interpret: bool):
             jax.ShapeDtypeStruct((bh, n, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, x, u, a_mod)

@@ -10,6 +10,15 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules
+    (distributed/sharding.py) annotate arrays and let GSPMD propagate,
+    which ``Explicit`` axes (``make_mesh``'s default) refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, seq: int = 1,
@@ -35,24 +44,24 @@ def make_production_mesh(*, multi_pod: bool = False, seq: int = 1,
         if chips % (pipe * seq):
             raise ValueError(
                 f"pipe={pipe} × seq={seq} must divide the {chips}-chip pod")
-        return jax.make_mesh((chips // (pipe * seq), pipe, seq),
-                             ("data", "pipe", "seq"))
+        return _mesh((chips // (pipe * seq), pipe, seq),
+                     ("data", "pipe", "seq"))
     if seq == 1:
         shape = (2, 16, 16) if multi_pod else (16, 16)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-        return jax.make_mesh(shape, axes)
+        return _mesh(shape, axes)
     if 16 % seq:
         raise ValueError(f"seq={seq} must divide the 16-way data axis")
     shape = (2, 16 // seq, seq, 16) if multi_pod else (16 // seq, seq, 16)
     axes = (("pod", "data", "seq", "model") if multi_pod
             else ("data", "seq", "model"))
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_local_mesh():
     """Whatever this host has — used by tests/examples."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _mesh((n, 1), ("data", "model"))
 
 
 def make_seq_mesh(seq: int | None = None):
@@ -64,7 +73,7 @@ def make_seq_mesh(seq: int | None = None):
     seq = seq or n
     if n % seq:
         raise ValueError(f"seq={seq} must divide the device count {n}")
-    return jax.make_mesh((n // seq, seq, 1), ("data", "seq", "model"))
+    return _mesh((n // seq, seq, 1), ("data", "seq", "model"))
 
 
 def make_composed_mesh(*, data: int | None = None, pipe: int = 1,

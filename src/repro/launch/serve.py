@@ -11,8 +11,12 @@ the tokens must match exactly at temperature 0 — including under
 src/repro/spec/ and docs/serving.md).
 
   PYTHONPATH=src python -m repro.launch.serve --arch stablelm-1.6b \
-      --d-model 128 --n-layers 2 --requests 4 --prompt-len 32 --gen 16 \
+      --reduced --d-model 128 --requests 4 --prompt-len 32 --gen 16 \
       --speculate 4 --drafter self
+
+The model runs at its published widths unless ``--reduced`` asks for
+the tiny CPU shape (``ModelConfig.reduced()``), which ``--d-model`` and
+``--n-layers`` then override.
 
 Observability (src/repro/obs/, docs/observability.md): ``--trace PATH``
 writes a Chrome-trace/Perfetto JSON of every engine-step phase
@@ -46,8 +50,8 @@ replica + the router's ``router_*``/``ft_*`` families), and ``--check``
 still validates every stream against the naive baseline — migration
 included, because migrated streams are bit-identical::
 
-    python -m repro.launch.serve --router --replicas 2 --preempt-step 6 \
-        --requests 4 --prefix-cache -1
+    python -m repro.launch.serve --reduced --router --replicas 2 \
+        --preempt-step 6 --requests 4 --prefix-cache -1
 """
 
 from __future__ import annotations
@@ -59,6 +63,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import SpecConfig, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.obs import decisions as OD
 from repro.obs.trace import tracer
@@ -187,8 +192,13 @@ def run_router_workload(router, reqs, arrivals, *, preempt_step: int = 0):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-1.6b")
-    ap.add_argument("--d-model", type=int, default=128)
-    ap.add_argument("--n-layers", type=int, default=2)
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny CPU shape (ModelConfig.reduced()) instead "
+                         "of the published widths")
+    ap.add_argument("--d-model", type=int, default=0,
+                    help="override width (0 = keep)")
+    ap.add_argument("--n-layers", type=int, default=0,
+                    help="override depth (0 = keep)")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -269,9 +279,15 @@ def main():
                          "before serving and install the result (pair "
                          "with --tuning-table to also persist it)")
     args = ap.parse_args()
+    enable_compile_cache()
 
-    cfg = get_config(args.arch).reduced().with_(
-        d_model=args.d_model, n_layers=args.n_layers)
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    if args.d_model:
+        cfg = cfg.with_(d_model=args.d_model)
+    if args.n_layers:
+        cfg = cfg.with_(n_layers=args.n_layers)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
 
     # calibration comes up FIRST: kernel block shapes resolve through

@@ -30,6 +30,7 @@ from repro.distributed import sharding as S
 from repro.distributed.ft import (PreemptionHandler, StragglerDetector,
                                   run_with_restarts)
 from repro.distributed.pipeline import bubble_fraction
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import (make_composed_mesh, make_local_mesh,
                                make_production_mesh, make_seq_mesh,
                                pipe_size, seq_size)
@@ -101,6 +102,10 @@ class TrainObs:
         self.collective_wire = r.gauge(
             "train_collective_wire_bytes_per_device",
             "modeled per-device wire bytes of one compiled step")
+        self.kernel_calls = r.gauge(
+            "train_kernel_calls",
+            "compiled Pallas kernel calls (tpu_custom_call) per compiled "
+            "step; 0 where the kernels are interpreted or gated off")
         self.seq_exchange_s = r.gauge(
             "train_seq_exchange_seconds",
             "measured seq-axis boundary-exchange time (log-depth "
@@ -128,9 +133,12 @@ class TrainObs:
             self.activation_bytes.set(float(mem.temp_size_in_bytes))
         except Exception:   # pragma: no cover — backend without analysis
             log.debug("memory_analysis unavailable", exc_info=True)
+        text = compiled.as_text()
+        self.kernel_calls.set(
+            text.count('custom_call_target="tpu_custom_call"'))
         try:
             from repro.distributed.hlo_analysis import collective_stats
-            stats = collective_stats(compiled.as_text())
+            stats = collective_stats(text)
             for op, n in stats.counts.items():
                 self.collective_count.labels(op=op).set(n)
             for op, b in stats.buffer_bytes.items():
@@ -402,6 +410,7 @@ def main():
                     help="train through the pure-jnp reference attention "
                          "instead of the fused Pallas kernels (custom VJP)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.d_model:

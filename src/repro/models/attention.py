@@ -368,10 +368,11 @@ def _fused_taylor_decode(params: Params, cfg: ModelConfig,
     """Route the one-token update+readout through the fused Pallas
     decode kernel (kernels/taylor_decode.py). MHA only (H == KV): the
     kernel works on flattened (B·H, ...) states with no GQA grouping."""
+    from repro.kernels.ops import interpret_mode
     from repro.kernels.taylor_decode import taylor_decode_kernel
 
     B, H, _, dh = q.shape
-    interp = jax.default_backend() != "tpu"
+    interp = interpret_mode()
     flat3 = lambda t: t.reshape(B * H, *t.shape[3:])   # (B,H,1,X,Y)->(BH,X,Y)
     n_flat = cache.n if cache.n.ndim == 0 else jnp.repeat(cache.n, H)
     st = T.TaylorState(s2=flat3(cache.s2), s1=flat3(cache.s1),

@@ -18,7 +18,7 @@ implementations and writes what it *measured*:
   entries model evaluated at real dtype widths (``source`` records
   which).
 * **block shapes**: the fused Pallas kernels timed over a candidate
-  ``(block_q, block_k)`` grid (interpret mode off-TPU), best wall time
+  ``(block_q, block_k)`` grid (interpret mode on the CPU), best wall time
   wins.
 
 A recorded decision log (PR 6 ``--decision-log`` JSONL) seeds the sweep:
@@ -134,12 +134,13 @@ def sweep_kernel_blocks(d: int, *, n: int = 256, reps: int = 3,
     """Best (block_q, block_k) for the fused Pallas kernels at this d.
 
     Times ``taylor_direct_attention`` + ``taylor_efficient_attention``
-    per candidate (interpret mode on non-TPU hosts, where the sweep
-    still orders candidates by the work the grid shape implies)."""
+    per candidate (interpret mode on the CPU, where the sweep still
+    orders candidates by the work the grid shape implies)."""
+    from repro.kernels.ops import interpret_mode
     from repro.kernels.taylor_direct import taylor_direct_attention
     from repro.kernels.taylor_efficient import taylor_efficient_attention
 
-    interpret = jax.default_backend() not in ("tpu",)
+    interpret = interpret_mode()
     if quick:
         candidates = candidates[:2]
         n, reps = min(n, 128), 1

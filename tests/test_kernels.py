@@ -199,3 +199,18 @@ class TestDecodeKernel:
             y, state = taylor_decode_kernel(state, q, k, v, interpret=True)
         assert bool(jnp.all(jnp.isfinite(y)))
         assert int(state.n) == 40
+
+
+class TestInterpretMode:
+    """One predicate decides compiled vs interpreted kernels."""
+
+    @pytest.mark.parametrize("platform,interpret", [("cpu", True),
+                                                    ("tpu", False)])
+    def test_follows_platform(self, monkeypatch, platform, interpret):
+        monkeypatch.setattr(ops.jax, "default_backend", lambda: platform)
+        assert ops.interpret_mode() is interpret
+
+    def test_other_platform_raises(self, monkeypatch):
+        monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops.interpret_mode()

@@ -235,6 +235,51 @@ class TestFaultTolerance:
 
 
 # ---------------------------------------------------------------------------
+# Persistent compilation cache placement (launch/compile_cache.py)
+# ---------------------------------------------------------------------------
+
+class TestCompileCache:
+    @pytest.fixture
+    def updates(self, monkeypatch):
+        calls = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda *a: calls.append(a))
+        return calls
+
+    def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path, updates):
+        from repro.launch.compile_cache import enable_compile_cache
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert updates == []
+
+    def test_default_dir_is_fixed_in_the_checkout(self, monkeypatch,
+                                                  updates):
+        from pathlib import Path
+
+        from repro.launch.compile_cache import enable_compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+        assert enable_compile_cache() == want
+        assert updates == [("jax_compilation_cache_dir", want)]
+
+
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    """No TPU: non-zero exit before any phase, and no result line."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    out = subprocess.run([sys.executable, str(root / "chip_smoke.py")],
+                         env=env, capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+    assert "no TPU" in out.stderr
+
+
+# ---------------------------------------------------------------------------
 # End-to-end: tiny train run with checkpoint/restart (the full FT loop)
 # ---------------------------------------------------------------------------
 
