@@ -164,14 +164,14 @@ def train_phase(cfg, *, seq_len: int, batch=4, steps=3, mesh=None,
                 mesh=mesh or make_local_mesh(), obs=obs, log_every=1)
     wall = time.perf_counter() - t0
     losses = [float(x) for x in out["losses"]]
-    tok_per_s = obs.tokens_per_sec.value
-    last_step_s = batch * seq_len / tok_per_s
+    tok_per_s = obs.tokens_per_sec.value      # steps after the first
+    step_s = batch * seq_len / tok_per_s
     kernels = int(obs.kernel_calls.value)
     ok = (len(losses) == steps and all(math.isfinite(x) for x in losses)
           and (kernels > 0 or not need_kernels))
     return {"seq_len": seq_len, "batch": batch, "losses": losses,
-            "wall_s": wall, "last_step_s": last_step_s,
-            "setup_and_compile_s_est": wall - steps * last_step_s,
+            "wall_s": wall, "step_s": step_s,
+            "setup_and_compile_s_est": wall - steps * step_s,
             "tok_per_s": tok_per_s,
             "kernel_calls": kernels, "ok": ok}
 

@@ -93,6 +93,7 @@ def taylor_decode_kernel(state: T.TaylorState, q, k, v, *, tau=1.0,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="taylor_decode",
     )(qs, qs, ks, ks, vh, state.s2)
 
     # small terms in jnp (<1 % of traffic)

@@ -140,4 +140,5 @@ def taylor_direct_attention(q, k, v, *, causal: bool = False,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="taylor_dir_fwd",
     )(q, k, v)

@@ -69,7 +69,7 @@ def _amod_kernel(k_ref, kc_ref, vh_ref, a_ref, acc, *, cf: int, d: int):
         a_ref[0] = acc[...]
 
 
-def _amod_call(k, vh, *, cf: int, block_k: int, interpret: bool):
+def _amod_call(k, vh, *, cf: int, block_k: int, interpret: bool, name: str):
     bh, n, d = k.shape
     nchunks = d // cf
     grid = (bh, nchunks, n // block_k)
@@ -88,6 +88,7 @@ def _amod_call(k, vh, *, cf: int, block_k: int, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(k, k, vh)
 
 
@@ -134,7 +135,7 @@ def _readout_kernel(q_ref, qc_ref, a_ref, kv_ref, s0_ref, o_ref, acc, *,
 
 
 def _readout_call(q, a_mod, kv, s0, *, cf: int, block_q: int, n_keys: int,
-                  out_scale: bool, out_dtype, interpret: bool,
+                  out_scale: bool, out_dtype, interpret: bool, name: str,
                   coefs: tuple | None = None, divide: bool = True):
     bh, n, d = q.shape
     alpha = float(d) ** 0.25
@@ -162,6 +163,7 @@ def _readout_call(q, a_mod, kv, s0, *, cf: int, block_q: int, n_keys: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(q, q, a_mod, kv, s0)
 
 
@@ -218,10 +220,12 @@ def taylor_efficient_attention(q, k, v, *, block_q: int | None = None,
 
     vh = build_vhat(v, m_valid)
 
-    a_mod = _amod_call(k, vh, cf=cf, block_k=block_k, interpret=interpret)
+    a_mod = _amod_call(k, vh, cf=cf, block_k=block_k, interpret=interpret,
+                       name="taylor_eff_fwd_amod")
     # small summaries — plain XLA ops (negligible traffic)
     kv = jnp.einsum("bnd,bnf->bdf", k.astype(jnp.float32), vh)
     s0 = jnp.sum(vh, axis=1, keepdims=True)
     return _readout_call(q, a_mod, kv, s0, cf=cf, block_q=block_q,
                          n_keys=m_valid, out_scale=out_scale,
-                         out_dtype=v.dtype, interpret=interpret)
+                         out_dtype=v.dtype, interpret=interpret,
+                         name="taylor_eff_fwd_readout")

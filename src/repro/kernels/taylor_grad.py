@@ -169,6 +169,7 @@ def _direct_bwd_call(q, k, v, gaux, *, causal: bool, block_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="taylor_dir_bwd_dq",
     )(q, gaux, k, v)
 
     dk, dv = pl.pallas_call(
@@ -193,6 +194,7 @@ def _direct_bwd_call(q, k, v, gaux, *, causal: bool, block_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="taylor_dir_bwd_dkv",
     )(k, v, q, gaux)
     return dq, dk, dv
 
@@ -307,7 +309,8 @@ def _sym_quad_kernel(x_ref, xc_ref, u_ref, a_ref, o1_ref, o2_ref, acc, *,
         o2_ref[0] = acc[...].astype(o2_ref.dtype)
 
 
-def _sym_quad_call(x, u, a_mod, *, cf: int, block_q: int, interpret: bool):
+def _sym_quad_call(x, u, a_mod, *, cf: int, block_q: int, interpret: bool,
+                   name: str):
     """(BH, N, d), (BH, N, d+1), (BH, d², d+1) -> (BH, N, d)."""
     bh, n, d = x.shape
     grid = (bh, n // block_q, d // cf)
@@ -332,6 +335,7 @@ def _sym_quad_call(x, u, a_mod, *, cf: int, block_q: int, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(x, x, u, a_mod)
     return o1 + o2
 
@@ -350,13 +354,14 @@ def _efficient_vjp_fwd(cfg, q, k, v):
     bh, n, d = q.shape
     cf = _pick_chunk_factor(d)
     vh = build_vhat(v, m_valid)
-    a_mod = _amod_call(k, vh, cf=cf, block_k=block_k, interpret=interpret)
+    a_mod = _amod_call(k, vh, cf=cf, block_k=block_k, interpret=interpret,
+                       name="taylor_eff_fwd_amod")
     kv = jnp.einsum("bmd,bmf->bdf", k.astype(jnp.float32), vh)
     s0 = jnp.sum(vh, axis=1, keepdims=True)
     yhat = _readout_call(q, a_mod, kv, s0, cf=cf, block_q=block_q,
                          n_keys=m_valid, out_scale=False,
                          out_dtype=jnp.float32, interpret=interpret,
-                         divide=False)
+                         name="taylor_eff_fwd_readout", divide=False)
     den, y0 = yhat[..., :1], yhat[..., 1:] / yhat[..., :1]
     y = y0 * (float(m_valid) / d) ** 0.5 if out_scale else y0
     return y.astype(v.dtype), (q, k, v, den, y0)
@@ -376,26 +381,32 @@ def _efficient_vjp_bwd(cfg, res, g):
     ghat = jnp.concatenate(
         [-jnp.sum(g0 * y0, axis=-1, keepdims=True) / den, g0 / den], axis=-1)
 
-    # A_mod / KV̂ recomputed rather than saved (ISSUE: recompute-based)
+    # A_mod / KV̂ recomputed rather than saved. The call keeps the
+    # forward's name: it is the forward's call, operand for operand, so
+    # under remat XLA merges it with the rematerialised forward's A_mod
+    # in the same backward pass; a name of its own would make that a
+    # second kernel launch
     vh = build_vhat(v, m_valid)
-    a_mod = _amod_call(k, vh, cf=cf, block_k=block_k, interpret=interpret)
+    a_mod = _amod_call(k, vh, cf=cf, block_k=block_k, interpret=interpret,
+                       name="taylor_eff_fwd_amod")
     kv = jnp.einsum("bmd,bmf->bdf", k.astype(jnp.float32), vh)
 
     dA = 0.5 * _amod_call(q, ghat, cf=cf, block_k=block_q,
-                          interpret=interpret)
+                          interpret=interpret, name="taylor_eff_bwd_amod_da")
     dKV = (alpha ** 2) * jnp.einsum("bnd,bnf->bdf", q, ghat)
     dS0 = (alpha ** 4) * jnp.sum(ghat, axis=1, keepdims=True)
 
     dq = 0.5 * _sym_quad_call(q, ghat, a_mod, cf=cf, block_q=block_q,
-                              interpret=interpret)
+                              interpret=interpret,
+                              name="taylor_eff_bwd_symquad_dq")
     dq += (alpha ** 2) * jnp.einsum("bnf,bdf->bnd", ghat, kv)
     dk = _sym_quad_call(k, vh, dA, cf=cf, block_q=block_k,
-                        interpret=interpret)
+                        interpret=interpret, name="taylor_eff_bwd_symquad_dk")
     dk += jnp.einsum("bmf,bdf->bmd", vh, dKV)
     dvh = _readout_call(k, dA, dKV, dS0, cf=cf, block_q=block_k, n_keys=m,
                         out_scale=False, out_dtype=jnp.float32,
-                        interpret=interpret, coefs=(1.0, 1.0, 1.0),
-                        divide=False)
+                        interpret=interpret, name="taylor_eff_bwd_readout_dv",
+                        coefs=(1.0, 1.0, 1.0), divide=False)
     if m_valid < m:
         dvh = dvh * (jnp.arange(m) < m_valid)[None, :, None]
     dv = dvh[..., 1:]

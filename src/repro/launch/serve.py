@@ -27,7 +27,9 @@ prefix-cache and speculation counters) at exit and ``--metrics-port N``
 serves it live on ``http://localhost:N/metrics``; ``--decision-log
 PATH`` writes every ``select_backend`` record as JSONL — replaying
 exactly how the engine's ServePlan and each trace-time attention site
-were chosen. All of it observational: streams are bit-identical with
+were chosen; ``--profile-dir DIR`` captures a ``jax.profiler`` trace
+of the workload, the device's operations with every engine-step span
+on one clock. All of it observational: streams are bit-identical with
 every flag on or off.
 
 Fleet mode: ``--replica NAME`` names this process — it threads into
@@ -66,7 +68,7 @@ from repro.configs import SpecConfig, get_config
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.obs import decisions as OD
-from repro.obs.trace import tracer
+from repro.obs.trace import profile, tracer
 from repro.serve import Engine, EngineConfig, Request
 from repro.tune import table as TT
 
@@ -236,10 +238,11 @@ def main():
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="write a Chrome-trace JSON of every engine-step "
                          "phase (open in chrome://tracing or Perfetto)")
-    ap.add_argument("--annotate-steps", action="store_true",
-                    help="with --trace: also enter jax.profiler "
-                         "StepTraceAnnotation per engine step (correlates "
-                         "a simultaneous device profile)")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="capture a jax.profiler trace of the workload "
+                         "into DIR: device operations with every "
+                         "engine-step span on the same clock "
+                         "(TensorBoard/Perfetto)")
     ap.add_argument("--metrics-file", default=None, metavar="PATH",
                     help="write the Prometheus text exposition at exit")
     ap.add_argument("--metrics-snapshot", default=None, metavar="PATH",
@@ -311,7 +314,7 @@ def main():
     if args.replica:
         tracer.set_process_name(args.replica)
     if args.trace:
-        tracer.enable(annotate_steps=args.annotate_steps)
+        tracer.enable()
     if args.decision_log:
         OD.log.enable()
 
@@ -347,9 +350,12 @@ def main():
     reqs, arrivals = mixed_arrival_workload(
         cfg, args.requests, args.prompt_len, args.gen,
         top_k=args.top_k, top_p=args.top_p, shared_frac=args.shared_prefix)
+    with profile(args.profile_dir):
+        results = (run_router_workload(router, reqs, arrivals,
+                                       preempt_step=args.preempt_step)
+                   if router is not None
+                   else run_workload(engine, reqs, arrivals))
     if router is not None:
-        results = run_router_workload(router, reqs, arrivals,
-                                      preempt_step=args.preempt_step)
         routed = {rid: int(c.value) for rid, c in
                   [(r, router._requests_c.labels(replica=r))
                    for r in sorted({*router.replicas,
@@ -362,7 +368,6 @@ def main():
             "wire_bytes": int(router._wire_c.value),
             "epoch": router.membership.epoch}, indent=2))
     else:
-        results = run_workload(engine, reqs, arrivals)
         summary = engine.stats.summary()
         print(json.dumps(summary, indent=2))
         shared = max((m.active_decoding for m in engine.stats.steps),

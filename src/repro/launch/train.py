@@ -39,7 +39,7 @@ from repro.launch.steps import (build_train_step, default_opt_config,
 from repro.models import backend as B
 from repro.models import model as M
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import tracer
+from repro.obs.trace import profile, tracer
 from repro.optim import make_optimizer
 
 log = logging.getLogger("repro.train")
@@ -48,10 +48,12 @@ log = logging.getLogger("repro.train")
 class TrainObs:
     """Step-loop observability, same surfaces as the serving path
     (docs/observability.md): a MetricsRegistry rendered to Prometheus
-    text via :meth:`write`, plus the process-global tracer — callers
-    enable it and one ``train_step`` span per step lands in the Chrome
-    trace, so pipeline-bubble stalls show up in Perfetto next to the
-    jit-warmup (``compile=true``) span."""
+    text via :meth:`write`, plus the process-global tracer — one
+    ``train_step`` span per step (``input_batch``, ``step_dispatch``,
+    ``loss_readback`` inside) lands in the Chrome trace when callers
+    enable it and in any captured ``jax.profiler`` trace, so
+    pipeline-bubble stalls show up in Perfetto next to the jit-warmup
+    (``compile=true``) span."""
 
     def __init__(self):
         self.registry = MetricsRegistry()
@@ -62,7 +64,10 @@ class TrainObs:
                      10.0, 30.0, 120.0))
         self.tokens_per_sec = r.gauge(
             "train_tokens_per_second",
-            "global_batch x seq_len / last step wall time")
+            "tokens of the steps since the first one (which compiles) "
+            "over their wall time")
+        self._rate_tokens = 0
+        self._rate_s = 0.0
         self.loss = r.gauge("train_loss", "last step loss")
         self.steps_total = r.counter("train_steps_total",
                                      "optimizer steps run")
@@ -163,9 +168,16 @@ class TrainObs:
         self.seq_exchange_s.set(probe["seconds"])
         self.seq_exchange_b.set(probe["bytes_per_device"])
 
-    def observe(self, *, dt: float, tokens: int, loss: float) -> None:
+    def observe(self, *, dt: float, tokens: int, loss: float,
+                first: bool = False) -> None:
+        """One finished step; ``first`` marks the step that compiled,
+        which the tokens-per-second rate leaves out."""
         self.step_time.observe(dt)
-        self.tokens_per_sec.set(tokens / max(dt, 1e-9))
+        if not first:
+            self._rate_tokens += tokens
+            self._rate_s += dt
+            self.tokens_per_sec.set(self._rate_tokens
+                                    / max(self._rate_s, 1e-9))
         self.loss.set(loss)
         self.steps_total.inc()
         self.host_step.labels(host=self._host).set(dt)
@@ -224,21 +236,26 @@ def train(cfg, *, steps: int, global_batch: int, seq_len: int,
                     if step >= steps:
                         break
                     t0 = time.time()
-                    batch = device_put_batch(batch, mesh)
-                    if not obs_compiled:
-                        obs.record_compiled(step_fn, params, opt_state,
-                                            batch)
-                        obs_compiled = True
                     with tracer.span("train_step", step_num=step,
                                      compile_key="train_step"):
-                        params, opt_state, metrics = step_fn(
-                            params, opt_state, batch)
-                        loss = float(metrics["loss"])
+                        with tracer.span("input_batch"):
+                            batch = device_put_batch(batch, mesh)
+                        if not obs_compiled:
+                            obs.record_compiled(step_fn, params, opt_state,
+                                                batch)
+                            obs_compiled = True
+                        # returns once the step is enqueued; the loss's
+                        # read-back is where the host waits on the device
+                        with tracer.span("step_dispatch"):
+                            params, opt_state, metrics = step_fn(
+                                params, opt_state, batch)
+                        with tracer.span("loss_readback"):
+                            loss = float(metrics["loss"])
                     dt = time.time() - t0
                     detector.observe(dt)
                     if obs is not None:
                         obs.observe(dt=dt, tokens=global_batch * seq_len,
-                                    loss=loss)
+                                    loss=loss, first=step == start_step)
                     losses.append(loss)
                     if step % log_every == 0:
                         log.info("step %d loss %.4f gnorm %.3f (%.2fs)",
@@ -331,21 +348,24 @@ def train_composed(cfg, *, steps: int, global_batch: int, seq_len: int,
                     if step >= steps:
                         break
                     t0 = time.time()
-                    batch = device_put_batch(batch, mesh)
-                    if not obs_compiled:
-                        obs.record_compiled(step_fn, params, opt_state,
-                                            batch)
-                        obs_compiled = True
                     with tracer.span("train_step", step_num=step,
                                      compile_key="composed_step"):
-                        params, opt_state, metrics = step_fn(
-                            params, opt_state, batch)
-                        loss = float(metrics["loss"])
+                        with tracer.span("input_batch"):
+                            batch = device_put_batch(batch, mesh)
+                        if not obs_compiled:
+                            obs.record_compiled(step_fn, params, opt_state,
+                                                batch)
+                            obs_compiled = True
+                        with tracer.span("step_dispatch"):
+                            params, opt_state, metrics = step_fn(
+                                params, opt_state, batch)
+                        with tracer.span("loss_readback"):
+                            loss = float(metrics["loss"])
                     dt = time.time() - t0
                     detector.observe(dt)
                     if obs is not None:
                         obs.observe(dt=dt, tokens=global_batch * seq_len,
-                                    loss=loss)
+                                    loss=loss, first=step == start_step)
                     losses.append(loss)
                     if step % log_every == 0:
                         log.info("step %d loss %.4f gnorm %.3f (%.2fs)",
@@ -401,8 +421,10 @@ def main():
                     help="write Prometheus text metrics here at exit")
     ap.add_argument("--trace", default="",
                     help="write a Chrome trace of train_step spans here")
-    ap.add_argument("--annotate-steps", action="store_true",
-                    help="add jax.profiler step annotations to spans")
+    ap.add_argument("--profile-dir", default="", metavar="DIR",
+                    help="capture a jax.profiler trace of the run into "
+                         "DIR: device operations with the train_step "
+                         "spans on the same clock (TensorBoard/Perfetto)")
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--restartable", action="store_true",
                     help="wrap in the fault-tolerant supervision loop")
@@ -437,7 +459,7 @@ def main():
 
     obs = TrainObs() if (args.metrics_file or args.trace) else None
     if args.trace:
-        tracer.enable(annotate_steps=args.annotate_steps)
+        tracer.enable()
 
     def go(_state=None):
         if pp > 1:
@@ -451,10 +473,11 @@ def main():
                      seq_len=args.seq, mesh=mesh,
                      ckpt_dir=args.ckpt_dir or None, obs=obs)
 
-    if args.restartable:
-        out = run_with_restarts(lambda: None, go)
-    else:
-        out = go()
+    with profile(args.profile_dir):
+        if args.restartable:
+            out = run_with_restarts(lambda: None, go)
+        else:
+            out = go()
     if args.trace:
         tracer.write(args.trace)
         tracer.disable()

@@ -3,9 +3,9 @@ fleet layer on top (docs/observability.md).
 
 Process-local pillars:
 
-* ``obs.trace``     — nested span tracer, Chrome-trace/Perfetto export,
-  jit-compile tagging, optional ``jax.profiler`` step correlation;
-  global instance ``obs.tracer``. Fleet-aware: real ``os.getpid()``
+* ``obs.trace``     — nested span tracer into any captured
+  ``jax.profiler`` trace and a Chrome-trace/Perfetto buffer, jit-compile
+  tagging; global instance ``obs.tracer``. Fleet-aware: real ``os.getpid()``
   stamps, process-name metadata events, epoch offsets for
   cross-process merge.
 * ``obs.metrics``   — counters/gauges/histograms with labels +
@@ -29,7 +29,7 @@ Invariant (design.md §4.6): purely observational. The pillars are
 write-only from the serving/dispatch hot paths — nothing reads them
 back into scheduling, selection, or sampling — and everything except
 the always-on metrics counters is off by default with one-flag-check
-overhead. Aggregation and SLO evaluation read metrics *offline* (a
+overhead (a span also asks whether a profile is being captured). Aggregation and SLO evaluation read metrics *offline* (a
 snapshot or exported file), never from the hot path.
 """
 

@@ -1,29 +1,40 @@
-"""Span tracer: nested spans -> Chrome-trace/Perfetto JSON.
+"""Span tracer: nested spans -> Chrome-trace/Perfetto JSON and the
+``jax.profiler`` trace.
 
 One global ``tracer`` (module-level, like ``decisions.log``) that the
-serving engine wraps around every step phase — admission, prefix-cache
-lookup, prefill chunk, decode batch, draft, verify, rollback — plus
-whatever benchmarks and launchers want to mark. Spans nest naturally
-(``with tracer.span("decode_batch"):``) and export as Chrome trace
-event JSON (``ph: "B"/"E"`` pairs) that chrome://tracing and
-https://ui.perfetto.dev open directly.
+serving engine wraps around every step phase — admission, planning,
+prefix-cache lookup, prefill chunk, decode dispatch and read-back,
+draft, verify, rollback — plus whatever launchers want to mark. Spans
+nest naturally (``with tracer.span("decode_batch"):``) and go to two
+sinks, each on its own switch:
 
-Zero overhead when off: ``span()`` checks one flag and returns a shared
+* **the profiler** — whenever a ``jax.profiler`` trace is being
+  captured (``jax.profiler.trace``/``start_trace``, ``--profile-dir``
+  on the launchers, any harness's traced window), each span also enters
+  a ``jax.profiler.TraceAnnotation`` of the same name, with its scalar
+  args as the annotation's arguments. The spans then sit on the
+  profiler's clock beside the device's operations, so a device idle
+  gap can be put down to the host phase that covers it;
+* **the Chrome-trace buffer** — after ``enable()``, ``B``/``E`` event
+  pairs on ``time.perf_counter`` that chrome://tracing and
+  https://ui.perfetto.dev open directly (``write``/``export``).
+
+Near zero overhead when both are off: ``span()`` checks one flag and
+asks the profiler whether it is capturing
+(``TraceAnnotation.is_enabled()``, tens of ns), then returns a shared
 no-op context manager — no event append, no timestamp read, no
-allocation. The engine is instrumented unconditionally; only an enabled
-tracer pays.
+allocation. The engine is instrumented unconditionally; only a capture
+or an enabled buffer pays.
 
-Two serving-specific extras:
+Span names starting with ``bench.`` are reserved for benchmark
+harnesses, which name device idle gaps by their own annotations;
+``span()`` refuses them.
 
-* **jit-compile detection** — pass ``compile_key=<hashable>`` and the
-  first span with that key is tagged ``args["compile"] = true``: the
-  engine keys on dispatch shapes (chunk length, draft k, slot count),
-  so warmup spans that trigger XLA compilation are visually separable
-  from steady-state dispatches of the same phase.
-* **``jax.profiler`` correlation** — with ``annotate_steps=True`` any
-  span carrying ``step_num`` also enters a
-  ``jax.profiler.StepTraceAnnotation``, so a simultaneously captured
-  device profile aligns its steps with this tracer's engine steps.
+Jit-compile detection: pass ``compile_key=<hashable>`` and, in the
+buffer, the first span with that key is tagged ``args["compile"] =
+true``: the engine keys on dispatch shapes (chunk length, draft k, slot
+count), so warmup spans that trigger XLA compilation are visually
+separable from steady-state dispatches of the same phase.
 
 Thread-safe: events append under a lock (cross-session replicas or a
 metrics HTTP thread may export mid-run), and ``tid`` records the
@@ -46,10 +57,17 @@ completed spans (B/E pairs + instants) and filter them by the
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
 import time
+
+import jax.profiler
+from jax.profiler import TraceAnnotation
+
+#: Span-name prefix reserved for benchmark harnesses' own annotations.
+RESERVED_PREFIX = "bench."
 
 
 class _NullSpan:
@@ -70,13 +88,27 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
+#: Arg types a profiler annotation can carry.
+_SCALAR = (int, float, str, bool)
+
+
+def _scalars(args: dict | None) -> dict:
+    if not args:
+        return {}
+    return {k: v for k, v in args.items() if isinstance(v, _SCALAR)}
+
+
 class Span:
-    """One live span; ``set(k, v)`` attaches args visible in the trace."""
+    """One live span; ``set(k, v)`` attaches args visible in the trace.
+
+    ``tracer`` is the buffer to write (None when only the profiler is
+    capturing); ``annotation`` the profiler annotation (None when it is
+    not)."""
 
     __slots__ = ("_tracer", "name", "_args", "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, args: dict | None,
-                 annotation=None):
+    def __init__(self, tracer: "Tracer | None", name: str,
+                 args: dict | None, annotation=None):
         self._tracer = tracer
         self.name = name
         self._args = args
@@ -86,12 +118,15 @@ class Span:
         if self._args is None:
             self._args = {}
         self._args[key] = value
+        if self._ann is not None and isinstance(value, _SCALAR):
+            self._ann.set_metadata(**{key: value})
         return self
 
     def __enter__(self):
         if self._ann is not None:
             self._ann.__enter__()
-        self._tracer._emit("B", self.name, self._args)
+        if self._tracer is not None:
+            self._tracer._emit("B", self.name, self._args)
         # args dict is shared with the B event: set() after enter still
         # lands in the exported trace
         return self
@@ -99,9 +134,10 @@ class Span:
     def __exit__(self, *exc):
         # error goes on the E event: the B event's args dict was already
         # emitted (and may be None when the span opened bare)
-        self._tracer._emit(
-            "E", self.name,
-            {"error": exc[0].__name__} if exc[0] is not None else None)
+        if self._tracer is not None:
+            self._tracer._emit(
+                "E", self.name,
+                {"error": exc[0].__name__} if exc[0] is not None else None)
         if self._ann is not None:
             self._ann.__exit__(*exc)
         return False
@@ -110,9 +146,8 @@ class Span:
 class Tracer:
     """Thread-safe span/event buffer with Chrome-trace export."""
 
-    def __init__(self, *, annotate_steps: bool = False):
+    def __init__(self):
         self.enabled = False
-        self.annotate_steps = annotate_steps
         self.events: list[dict] = []
         self._lock = threading.Lock()
         self._seen_keys: set = set()
@@ -125,9 +160,9 @@ class Tracer:
         lands in the ``process_name`` metadata event and ``otherData``."""
         self.process_name = name
 
-    def enable(self, *, annotate_steps: bool | None = None) -> None:
-        if annotate_steps is not None:
-            self.annotate_steps = annotate_steps
+    def enable(self) -> None:
+        """Write spans into the Chrome-trace buffer (the profiler gets
+        them whenever it captures, enabled or not)."""
         self.enabled = True
 
     def disable(self) -> None:
@@ -140,39 +175,35 @@ class Tracer:
 
     # -- spans --------------------------------------------------------------
 
-    def span(self, name: str, *, compile_key=None, step_num=None,
+    def span(self, name: str, *, compile_key=None,
              **args) -> Span | _NullSpan:
-        """Context manager for one nested span.
+        """Context manager for one nested span; ``args`` land on both
+        sinks (the profiler keeps the scalar ones).
 
-        ``compile_key``: hashable dispatch-shape key; the first span per
-        key is tagged ``compile=true`` (jit warmup detection).
-        ``step_num``: with ``annotate_steps``, correlates this span with
-        a ``jax.profiler`` step annotation of the same number.
+        ``compile_key``: hashable dispatch-shape key; in the buffer the
+        first span per key is tagged ``compile=true`` (jit warmup
+        detection).
         """
-        if not self.enabled:
+        profiling = TraceAnnotation.is_enabled()
+        if not (self.enabled or profiling):
             return _NULL
+        if name.startswith(RESERVED_PREFIX):
+            raise ValueError(f"span names starting with "
+                             f"{RESERVED_PREFIX!r} are reserved: {name!r}")
         a = dict(args) if args else None
-        if compile_key is not None:
-            first = compile_key not in self._seen_keys
-            if first:
-                self._seen_keys.add(compile_key)
-                a = a or {}
-                a["compile"] = True
-        ann = None
-        if step_num is not None:
-            if a is None:
-                a = {}
-            a["step_num"] = step_num
-            if self.annotate_steps:
-                try:
-                    from jax.profiler import StepTraceAnnotation
-                    ann = StepTraceAnnotation(name, step_num=step_num)
-                except Exception:  # profiler unavailable: spans still work
-                    ann = None
-        return Span(self, name, a, ann)
+        if (self.enabled and compile_key is not None
+                and compile_key not in self._seen_keys):
+            self._seen_keys.add(compile_key)
+            a = a or {}
+            a["compile"] = True
+        ann = TraceAnnotation(name, **_scalars(a)) if profiling else None
+        return Span(self if self.enabled else None, name, a, ann)
 
     def instant(self, name: str, **args) -> None:
         """A zero-duration marker event."""
+        if TraceAnnotation.is_enabled():
+            with TraceAnnotation(name, **_scalars(args)):
+                pass
         if self.enabled:
             self._emit("i", name, dict(args) if args else None)
 
@@ -236,6 +267,19 @@ class Tracer:
 
 #: The process-global tracer every instrumented module shares.
 tracer = Tracer()
+
+
+def profile(log_dir: str | None):
+    """Capture a ``jax.profiler`` trace into ``log_dir`` around a block
+    (the launchers' ``--profile-dir``); a no-op without a directory.
+    The tracer's spans land in it beside the device's operations; the
+    Python function tracer stays off, as in a benchmark's traced window,
+    so the host timeline holds the spans and not every Python call."""
+    if not log_dir:
+        return contextlib.nullcontext()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return jax.profiler.trace(log_dir, profiler_options=opts)
 
 
 # ---------------------------------------------------------------------------

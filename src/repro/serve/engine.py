@@ -516,8 +516,10 @@ class Engine:
         t0 = time.perf_counter()
         events: list[TokenEvent] = []
         # every phase below is wrapped in an obs span; with the global
-        # tracer disabled (the default) each wrapper is one flag check
-        # returning a shared no-op context — docs/observability.md
+        # tracer's buffer off and no profile capturing (the default) each
+        # wrapper is two checks returning a shared no-op context — in a
+        # captured profile the spans name the host work the device waits
+        # on (docs/observability.md)
         step_span = tracer.span("engine_step", step_num=self._step_idx)
         with step_span:
 
@@ -549,8 +551,9 @@ class Engine:
                     admitted += 1
                 adm.set("admitted", admitted)
 
-            plan = self.scheduler.plan(
-                [s for s in self._slots if s is not None])
+            with tracer.span("plan"):
+                plan = self.scheduler.plan(
+                    [s for s in self._slots if s is not None])
             budget = self.scheduler.token_budget
 
             # 2. one batched decode (or draft+verify) pass for every
@@ -589,26 +592,32 @@ class Engine:
                     dec_span.set("requests",
                                  [s.request_id for s in plan.decode])
                 with dec_span:
-                    tokens = np.zeros((self.pool.n_slots, 1), np.int32)
-                    mask = np.zeros((self.pool.n_slots,), bool)
-                    for s in plan.decode:
-                        tokens[s.slot, 0] = s.next_token
-                        mask[s.slot] = True
-                    logits, self.pool.cache = self._decode_fn(
-                        jnp.asarray(tokens), jnp.asarray(mask),
-                        self.pool.cache)
-                    last = logits[:, -1]
+                    # dispatch returns once the step is enqueued; the
+                    # device runs it while the read-back below waits
+                    with tracer.span("decode_dispatch"):
+                        tokens = np.zeros((self.pool.n_slots, 1), np.int32)
+                        mask = np.zeros((self.pool.n_slots,), bool)
+                        for s in plan.decode:
+                            tokens[s.slot, 0] = s.next_token
+                            mask[s.slot] = True
+                        logits, self.pool.cache = self._decode_fn(
+                            jnp.asarray(tokens), jnp.asarray(mask),
+                            self.pool.cache)
+                        last = logits[:, -1]
                     # one batched argmax + one device sync covers every
                     # greedy row; skipped when the whole batch is sampled
                     greedy = None
                     if any(self._temp(s) <= 0.0 for s in plan.decode):
-                        greedy = np.asarray(jnp.argmax(last, axis=-1))
-                    for s in plan.decode:
-                        if self._temp(s) <= 0.0:
-                            events.append(self._emit(s, int(greedy[s.slot])))
-                        else:
-                            events.append(
-                                self._emit(s, self._sample(s, last[s.slot])))
+                        with tracer.span("decode_readback"):
+                            greedy = np.asarray(jnp.argmax(last, axis=-1))
+                    with tracer.span("emit"):
+                        for s in plan.decode:
+                            if self._temp(s) <= 0.0:
+                                events.append(
+                                    self._emit(s, int(greedy[s.slot])))
+                            else:
+                                events.append(self._emit(
+                                    s, self._sample(s, last[s.slot])))
                 decode_tokens = len(plan.decode)
                 decode_charge = self.scheduler.decode_cost(len(plan.decode))
                 budget -= decode_charge
@@ -692,18 +701,22 @@ class Engine:
                         break
                     self._begin_decode(s, events)
 
-        m = StepMetrics(
-            step=self._step_idx, wall_s=time.perf_counter() - t0,
-            decode_tokens=decode_tokens, prefill_tokens=prefill_tokens,
-            queue_depth=self.queue.depth, occupancy=self.pool.occupancy,
-            active_decoding=len(plan.decode),
-            draft_tokens=draft_tokens, accepted_tokens=accepted_tokens,
-            rollbacks=rollbacks, speculate_k=k_step,
-            cached_prefix_tokens=cached_tokens,
-            scheduled_tokens=decode_charge + prefill_tokens)
-        self.stats.record_step(m)
-        if self.prefix_cache is not None:
-            self.stats.prefix_cache = self.prefix_cache.stats()
+            with tracer.span("record_step"):
+                m = StepMetrics(
+                    step=self._step_idx, wall_s=time.perf_counter() - t0,
+                    decode_tokens=decode_tokens,
+                    prefill_tokens=prefill_tokens,
+                    queue_depth=self.queue.depth,
+                    occupancy=self.pool.occupancy,
+                    active_decoding=len(plan.decode),
+                    draft_tokens=draft_tokens,
+                    accepted_tokens=accepted_tokens,
+                    rollbacks=rollbacks, speculate_k=k_step,
+                    cached_prefix_tokens=cached_tokens,
+                    scheduled_tokens=decode_charge + prefill_tokens)
+                self.stats.record_step(m)
+                if self.prefix_cache is not None:
+                    self.stats.prefix_cache = self.prefix_cache.stats()
         self._step_idx += 1
         return m, events
 

@@ -111,7 +111,8 @@ class TestEfficientKernel:
         ones = jnp.ones((3, 64, 1), jnp.float32)
         vh = jnp.concatenate([ones, v], axis=-1)
         cf = _pick_chunk_factor(d)
-        a = _amod_call(k, vh, cf=cf, block_k=32, interpret=True)
+        a = _amod_call(k, vh, cf=cf, block_k=32, interpret=True,
+                       name="taylor_eff_fwd_amod")
         a_ref = ref.amod_ref(k, v)
         np.testing.assert_allclose(np.asarray(a), np.asarray(a_ref),
                                    rtol=1e-4, atol=1e-4)

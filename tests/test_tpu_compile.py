@@ -6,7 +6,8 @@ these tests catch what interpret mode cannot: block shapes not aligned
 to the tiling, vector reshapes Mosaic refuses, VMEM overruns. Nothing
 runs; each test only asserts that the compiled program holds the
 kernel (``tpu_custom_call``), i.e. that it was compiled and not
-interpreted.
+interpreted, under the name its call site gives it: the instruction
+``%<name>.N``, which is how a device trace names the kernel.
 
 Shapes are those of the models the repo serves and trains: head dim
 64, 32 flattened batch·heads, N = 4096.
@@ -17,6 +18,7 @@ tests live in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +64,21 @@ def _compiled_text(fn, *specs) -> str:
     return jax.jit(fn).lower(*specs).compile().as_text()
 
 
+def _kernels(text: str) -> list[str]:
+    """Names of the compiled kernels, ``%<name>.N`` -> ``<name>``; a call
+    under ``jax.grad`` outside a rematerialised block carries its
+    transformations too (``%transpose_jvp_<name>__.N``), dropped here."""
+    names = re.findall(r'%([\w\-]+)\.\d+ = [^\n]*custom_call_target='
+                       r'"tpu_custom_call"', text)
+    return [re.sub(r"^(transpose_)?(jvp_)?", "", n).rstrip("_")
+            for n in names]
+
+
+EFFICIENT_FWD = ["taylor_eff_fwd_amod", "taylor_eff_fwd_readout"]
+EFFICIENT_BWD = ["taylor_eff_bwd_amod_da", "taylor_eff_bwd_symquad_dq",
+                 "taylor_eff_bwd_symquad_dk", "taylor_eff_bwd_readout_dv"]
+
+
 @pytest.fixture
 def qkv(one_chip):
     return [_spec((BH, N, D), one_chip) for _ in range(3)]
@@ -73,6 +90,7 @@ def test_direct_forward_compiles(qkv, causal):
         lambda q, k, v: taylor_direct_attention(q, k, v, causal=causal,
                                                 interpret=False), *qkv)
     assert "tpu_custom_call" in text
+    assert _kernels(text) == ["taylor_dir_fwd"]
 
 
 def test_direct_grad_compiles(qkv):
@@ -81,6 +99,8 @@ def test_direct_grad_compiles(qkv):
                                                    interpret=False) ** 2)
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
     assert "tpu_custom_call" in text
+    assert sorted(_kernels(text)) == ["taylor_dir_bwd_dkv",
+                                      "taylor_dir_bwd_dq", "taylor_dir_fwd"]
 
 
 def test_efficient_forward_compiles(qkv):
@@ -88,6 +108,7 @@ def test_efficient_forward_compiles(qkv):
         lambda q, k, v: taylor_efficient_attention(
             q, k, v, block_q=128, block_k=128, interpret=False), *qkv)
     assert "tpu_custom_call" in text
+    assert _kernels(text) == EFFICIENT_FWD
 
 
 def test_efficient_grad_compiles(qkv):
@@ -96,6 +117,24 @@ def test_efficient_grad_compiles(qkv):
                                                       interpret=False) ** 2)
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
     assert "tpu_custom_call" in text
+    # the backward recomputes A_mod under the forward's name
+    assert sorted(_kernels(text)) == sorted(
+        EFFICIENT_FWD + ["taylor_eff_fwd_amod"] + EFFICIENT_BWD)
+
+
+def test_efficient_grad_under_remat_runs_eight_kernels(qkv):
+    """As a rematerialised layer trains it: forward, recomputed forward,
+    backward. The backward's A_mod, which keeps the forward's name, merges
+    with the recomputed forward's: a name of its own would make it a
+    ninth launch."""
+    attn = jax.checkpoint(lambda q, k, v: taylor_efficient_attention_vjp(
+        q, k, v, interpret=False))
+
+    def loss(q, k, v):
+        return jnp.sum(attn(q, k, v) ** 2)
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
+    assert sorted(_kernels(text)) == sorted(2 * EFFICIENT_FWD
+                                            + EFFICIENT_BWD)
 
 
 def test_decode_kernel_compiles(one_chip):
@@ -109,3 +148,4 @@ def test_decode_kernel_compiles(one_chip):
                                                  interpret=False),
         state, q, k, v)
     assert "tpu_custom_call" in text
+    assert _kernels(text) == ["taylor_decode"]
