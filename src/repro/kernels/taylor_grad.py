@@ -18,15 +18,20 @@ materializing what the forward avoided materializing:
   cotangent of ŷ_i, every gradient is a rank-structured contraction:
 
       dA_mod = ½ (Q^⊠2)ᵀ Ĝ                          (an amod pass over Q)
-      dq_i   = ½ (M_i + M_iᵀ) q_i + α² KV̂ ĝ_i,  M_i = mat(A_mod ĝ_i)
-      dk_j   = (W_j + W_jᵀ) k_j + dKV̂ v̂_j,      W_j = mat(dA_mod v̂_j)
+      dq_i   = M_i q_i + α² KV̂ ĝ_i,      M_i = mat(A_mod ĝ_i)
+      dk_j   = 2 W_j k_j + dKV̂ v̂_j,      W_j = mat(dA_mod v̂_j)
       dv̂_j  = K^⊠2_j dA_mod + k_j dKV̂ + dS0      (a raw readout pass)
+
+  The chain rule gives ½ (M_i + M_iᵀ) q_i and (W_j + W_jᵀ) k_j; row
+  π(a, b) of A_mod sums k_a·k_b v̂ over keys (of dA_mod, q_a·q_b ĝ over
+  queries), so both are symmetric in (a, b), M_i = M_iᵀ and W_j = W_jᵀ.
 
   A_mod / KV̂ / ΣV̂ are *recomputed* from k, v̂ in the backward (they are
   cheaper to rebuild than to hold as residuals), and the N×d² expanded
   tensors are never formed in HBM: the symmetric-quadratic kernel below
-  streams cf·d-row chunks of A_mod through VMEM exactly like the
-  forward's two phases. Peak backward memory stays O(N·d + d³).
+  builds the ⊠ product of ĝ_i (v̂_j) and q_i (k_j) in VMEM, a block of
+  queries (keys) at a time, and meets A in one MXU contraction per
+  block. Peak backward memory stays O(N·d + d³).
 
 The causal chunked backward lives in ``core/taylor.py`` (pure-jnp
 two-scan recompute custom VJP) since the causal path is not a Pallas
@@ -263,81 +268,81 @@ def taylor_direct_attention_vjp(q, k, v, *, causal: bool = False,
 
 
 # ---------------------------------------------------------------------------
-# Efficient backward — symmetric-quadratic chunk kernel
+# Efficient backward — symmetric-quadratic kernel
 # ---------------------------------------------------------------------------
 
-def _sym_quad_kernel(x_ref, xc_ref, u_ref, a_ref, o1_ref, o2_ref, acc, *,
+def _sym_quad_kernel(xt_ref, u0t_ref, u1t_ref, a0_ref, a1_ref, o_ref, acc, *,
                      cf: int, d: int):
-    """out_i = (M_i + M_iᵀ) x_i with M_i = mat(A u_i), streamed over cf·d
-    row-chunks of A so the (N, d²) intermediate never leaves VMEM.
+    """(M_i + M_iᵀ) x_i = 2 M_i x_i with M_i = mat(A u_i), for a block of
+    queries i at once, as one MXU contraction per chunk of cf value
+    columns f of A.
 
-    Chunk c holds A rows π(a, b) for a ∈ [c·cf, (c+1)·cf). For each row
-    group a, t = u A_aᵀ (bq, d) is row a of M_i, so o1 (the M x term)
-    lands directly in output column a of this chunk, while the Mᵀ x term
-    needs x's *own* column a and accumulates over chunks into o2. Every
-    value stays 2-D: Mosaic refuses a reshape that splits the lane dim.
+    Precondition: A is symmetric in its row pair, A[π(a, b)] = A[π(b, a)]
+    (A_mod and dA are: row π(a, b) sums k_a·k_b over keys, or q_a·q_b
+    over queries), so M_i = M_iᵀ.
+
+    Everything is transposed, queries in lanes. With u = (u0, u1),
+    (M x)ᵀ = u0ᵀ ⊙ (A0 xᵀ) + Ã1 Pᵀ: A0[a, b] = A[π(a, b), 0] is the
+    ones/denominator column, Pᵀ[f·d + b, i] = u1[i, f]·x[i, b] is the ⊠
+    product of this chunk's columns of u1 with x, built by broadcasting
+    rows (no lane relayout), and Ã1[a, f·d + b] = A[π(a, b), 1 + f]. The
+    matmul's N is the query block, which fills the MXU's width.
     """
     ic = pl.program_id(2)
     nc = pl.num_programs(2)
+    xt = xt_ref[0]                                       # (d, bq) fp32
 
     @pl.when(ic == 0)
     def _init():
-        acc[...] = jnp.zeros_like(acc)
+        acc[...] = u0t_ref[0] * jax.lax.dot_general(
+            a0_ref[0], xt, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    x = x_ref[0].astype(jnp.float32)                     # (bq, d)
-    xc = xc_ref[0].astype(jnp.float32)                   # (bq, cf)
-    u = u_ref[0].astype(jnp.float32)                     # (bq, d+1)
-    lane = jax.lax.broadcasted_iota(jnp.int32, xc.shape, 1)
-
-    def row_group(a, carry):
-        o1, o2 = carry
-        rows = a_ref[0, pl.ds(pl.multiple_of(a * d, d), d), :]  # (d, d+1)
-        t = jax.lax.dot_general(u, rows, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        hit = lane == a
-        o1 = jnp.where(hit, jnp.sum(t * x, axis=1, keepdims=True), o1)
-        xa = jnp.sum(jnp.where(hit, xc, 0.0), axis=1, keepdims=True)
-        return o1, o2 + t * xa
-
-    o1, o2 = jax.lax.fori_loop(
-        0, cf, row_group, (jnp.zeros_like(xc), jnp.zeros_like(x)))
-    o1_ref[0] = o1.astype(o1_ref.dtype)
-    acc[...] += o2
+    ut = u1t_ref[0]                                      # (cf, bq)
+    pt = (ut[:, None, :] * xt[None, :, :]).reshape(cf * d, xt.shape[1])
+    acc[...] += jax.lax.dot_general(a1_ref[0], pt, (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
 
     @pl.when(ic == nc - 1)
     def _finish():
-        o2_ref[0] = acc[...].astype(o2_ref.dtype)
+        o_ref[0] = (2.0 * acc[...]).astype(o_ref.dtype)
 
 
 def _sym_quad_call(x, u, a_mod, *, cf: int, block_q: int, interpret: bool,
                    name: str):
-    """(BH, N, d), (BH, N, d+1), (BH, d², d+1) -> (BH, N, d)."""
+    """(BH, N, d), (BH, N, d+1), (BH, d², d+1) -> (BH, N, d):
+    (M_i + M_iᵀ) x_i for an A symmetric in its row pair (see the kernel).
+
+    The operands are laid out for the kernel in XLA: x and u transposed
+    (queries in lanes), and A's value column f moved from lanes into the
+    contraction, next to b. Each is one pass over an (N, d) or (d², d)
+    array, against the kernel's N·d³ multiply-adds.
+    """
     bh, n, d = x.shape
-    grid = (bh, n // block_q, d // cf)
-    o1, o2 = pl.pallas_call(
+    xt = jnp.swapaxes(x, 1, 2).astype(jnp.float32)       # (BH, d, N)
+    ut = jnp.swapaxes(u, 1, 2).astype(jnp.float32)       # (BH, d+1, N)
+    rows = a_mod.reshape(bh, d, d, d + 1)                # [a, b, f]
+    a0 = rows[..., 0]                                    # (BH, d, d)
+    a1 = jnp.swapaxes(rows[..., 1:], 2, 3).reshape(bh, d, d * d)
+    ot = pl.pallas_call(
         functools.partial(_sym_quad_kernel, cf=cf, d=d),
-        grid=grid,
+        grid=(bh, n // block_q, d // cf),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, block_q, cf), lambda b, i, c: (b, i, c)),
-            pl.BlockSpec((1, block_q, d + 1), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, cf * d, d + 1), lambda b, i, c: (b, c, 0)),
+            pl.BlockSpec((1, d, block_q), lambda b, i, c: (b, 0, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, c: (b, 0, i)),
+            pl.BlockSpec((1, cf, block_q), lambda b, i, c: (b, c, i)),
+            pl.BlockSpec((1, d, d), lambda b, i, c: (b, 0, 0)),
+            pl.BlockSpec((1, d, cf * d), lambda b, i, c: (b, 0, c)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, cf), lambda b, i, c: (b, i, c)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, c: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, n, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, n, d), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        out_specs=pl.BlockSpec((1, d, block_q), lambda b, i, c: (b, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((bh, d, n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(x, x, u, a_mod)
-    return o1 + o2
+    )(xt, ut[:, :1], ut[:, 1:], a0, a1)
+    return jnp.swapaxes(ot, 1, 2)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))

@@ -18,6 +18,9 @@ from _hypothesis_compat import given, settings, st
 
 from repro.core import taylor as T
 from repro.kernels import ops, ref
+from repro.kernels.taylor_efficient import (_amod_call, _pick_chunk_factor,
+                                            build_vhat)
+from repro.kernels.taylor_grad import _sym_quad_call
 
 jax.config.update("jax_enable_x64", False)
 
@@ -239,6 +242,63 @@ class TestKernelVJP:
         gr = jax.grad(fr, argnums=(0, 1, 2))(q, k, v)
         assert_grads_close(gk, gr, rtol=5e-4, atol=5e-4,
                            msg=f"prop {mode} n={n} d={d}")
+
+
+# ---------------------------------------------------------------------------
+# The efficient backward's symmetric-quadratic kernel, alone
+# ---------------------------------------------------------------------------
+
+def _sym_quad_operands(site, n, d, n_valid):
+    """Both call sites' real operands, α-scaled rows zero-padded past
+    ``n_valid`` as ops.py pads them: the dq site gives (q, ĝ, A_mod(k, v̂)),
+    the dk site (k, v̂, dA) with dA = ½ amod(q, ĝ)."""
+    ks = jax.random.split(jax.random.PRNGKey(n * d + n_valid), 4)
+    valid = (jnp.arange(n) < n_valid)[None, :, None]
+    q, k = (T.l2_normalize(jax.random.normal(kk, (2, n, d)))
+            * d ** 0.25 * valid for kk in ks[:2])
+    vh = build_vhat(jax.random.normal(ks[2], (2, n, d)), n_valid)
+    ghat = jax.random.normal(ks[3], (2, n, d + 1)) * valid
+    cf = _pick_chunk_factor(d)
+
+    def amod(x, u):
+        return _amod_call(x, u, cf=cf, block_k=16, interpret=True,
+                          name="taylor_eff_fwd_amod")
+    if site == "dq":
+        return q, ghat, amod(k, vh)
+    return k, vh, 0.5 * amod(q, ghat)
+
+
+@pytest.mark.kernels
+class TestSymQuadKernel:
+    """``_sym_quad_call`` returns (M_i + M_iᵀ) x_i, M_i = mat(A u_i), as
+    2 M_i x_i: exact only because A is symmetric in its row pair, which
+    the second test pins for ``_amod_call``'s output."""
+
+    @pytest.mark.parametrize("d", [8, 16, 32])
+    @pytest.mark.parametrize("n,n_valid", [(64, 64), (64, 61)])
+    @pytest.mark.parametrize("site", ["dq", "dk"])
+    def test_matches_einsum(self, site, n, n_valid, d):
+        x, u, a = _sym_quad_operands(site, n, d, n_valid)
+        out = _sym_quad_call(x, u, a, cf=_pick_chunk_factor(d), block_q=16,
+                             interpret=True,
+                             name=f"taylor_eff_bwd_symquad_{site}")
+        m = jnp.einsum("zef,znf->zne", a, u,
+                       precision="highest").reshape(2, n, d, d)
+        want = jnp.einsum("znab,znb->zna", m + jnp.swapaxes(m, -1, -2), x,
+                          precision="highest")
+        scale = float(jnp.max(jnp.abs(want)))
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5 * scale)
+        assert bool(jnp.all(out[:, n_valid:] == 0.0))
+
+    @pytest.mark.parametrize("d", [8, 16, 32])
+    @pytest.mark.parametrize("site", ["dq", "dk"])
+    def test_amod_rows_symmetric(self, site, d):
+        """The kernel's precondition: A_mod(k, v̂) and amod(q, ĝ) equal
+        their π(a, b) ↔ π(b, a) row permutation bit for bit."""
+        _, _, a = _sym_quad_operands(site, 64, d, 61)
+        rows = a.reshape(2, d, d, d + 1)
+        assert bool(jnp.all(rows == jnp.swapaxes(rows, 1, 2)))
 
 
 # ---------------------------------------------------------------------------

@@ -27,8 +27,10 @@ import pytest
 from repro.core import taylor as T
 from repro.kernels.taylor_decode import taylor_decode_kernel
 from repro.kernels.taylor_direct import taylor_direct_attention
-from repro.kernels.taylor_efficient import taylor_efficient_attention
-from repro.kernels.taylor_grad import (taylor_direct_attention_vjp,
+from repro.kernels.taylor_efficient import (_pick_chunk_factor,
+                                            taylor_efficient_attention)
+from repro.kernels.taylor_grad import (_sym_quad_call,
+                                       taylor_direct_attention_vjp,
                                        taylor_efficient_attention_vjp)
 
 pytestmark = pytest.mark.kernels
@@ -135,6 +137,20 @@ def test_efficient_grad_under_remat_runs_eight_kernels(qkv):
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
     assert sorted(_kernels(text)) == sorted(2 * EFFICIENT_FWD
                                             + EFFICIENT_BWD)
+
+
+@pytest.mark.parametrize("site", ["dq", "dk"])
+def test_sym_quad_kernel_compiles(one_chip, site):
+    """The backward's symmetric-quadratic pass alone: the ⊠ product of u
+    and x built in VMEM, one contraction against the re-laid-out A."""
+    name = f"taylor_eff_bwd_symquad_{site}"
+    text = _compiled_text(
+        lambda x, u, a: _sym_quad_call(x, u, a, cf=_pick_chunk_factor(D),
+                                       block_q=128, interpret=False,
+                                       name=name),
+        _spec((BH, N, D), one_chip), _spec((BH, N, D + 1), one_chip),
+        _spec((BH, D * D, D + 1), one_chip))
+    assert _kernels(text) == [name]
 
 
 def test_decode_kernel_compiles(one_chip):
