@@ -15,7 +15,15 @@ Metrics:
 - ``serve_gen_tokens_per_s``: tokens emitted by steps that ended in the
   window, over the window (first due time to the end of the last step);
 - ``itl_p95_ms``: 95th percentile of every gap between consecutive
-  tokens of one request whose later token was emitted in the window.
+  tokens of one request whose later token was emitted in the window;
+- ``ttft_p90_s``, where the mix has ``drain_s``: 90th percentile, over
+  the requests due in the window, of the time from each one's due time
+  to its first token. After the window the engine is stepped, with no
+  new submissions, until each of them has its first token or
+  ``drain_s`` has passed; a request refused or not served by then ranks
+  at the time from the first due time to the end of that drain, which
+  no served request's time exceeds. The drain comes after the window,
+  so no other metric counts it.
 
 Each step's wall time and the main thread's CPU time are kept, and the
 slowest steps are printed with the garbage collection that ran inside
@@ -47,7 +55,8 @@ def build(run):
 
     cfg = run.program_config()
     shapes = jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
-    params = make_params(shapes, seed=run.seed, d_model=cfg.d_model)
+    params = make_params(shapes, seed=run.seed, d_model=cfg.d_model,
+                         init=run.config.get("init"))
     econf = EngineConfig(**run.traffic["engine"])
     econf.max_seq_len = max(traffic_gen.longest_request(run.traffic),
                             2 * econf.prefill_chunk + 2)
@@ -139,17 +148,28 @@ class Window:
                 engine.pop_result(ev.request_id)
 
 
+def settler(engine, traffic: dict):
+    """Where the mix has ``"settle_trace": true``, a wait for the device
+    (on the slot pool), so that the traced part opens and closes with the
+    device idle and each program it holds was dispatched inside it; for
+    any other mix None, and the traced part opens and closes as it falls."""
+    if not traffic.get("settle_trace"):
+        return None
+    return lambda: jax.block_until_ready(engine.pool.cache)
+
+
 def drive(engine, arrivals, run):
     """The measured window. Returns the Window."""
     w = Window(arrivals)
     t0 = time.perf_counter()
     t_end = t0 + run.seconds
+    settle = settler(engine, run.traffic)
     step = 0
     while True:
         now = time.perf_counter()
         if now >= t_end:
             break
-        run.tracer.maybe_start(now - t0)
+        run.tracer.maybe_start(now - t0, settle)
         w.submit_due(engine, t0, now)
         if engine.idle:
             nxt = t0 + w.pending[0].due_s if w.pending else t_end
@@ -160,8 +180,34 @@ def drive(engine, arrivals, run):
         step += 1
     w.t0 = t0
     w.window_end = max(t_end, w.steps[-1][1]) if w.steps else t_end
+    if run.tracer.active and settle is not None:
+        settle()
     run.window_closed()
     return w
+
+
+def drain(engine, w, cap_s: float) -> None:
+    """Step on, with no new submissions, until every request due in the
+    window has its first token, the engine is idle, or ``cap_s`` has
+    passed."""
+    t_cap = time.perf_counter() + cap_s
+    refused = set(w.refused)
+    waiting = [r for r in w.due if r not in refused]
+    step = len(w.steps)
+    while (any(r not in w.first for r in waiting) and not engine.idle
+           and time.perf_counter() < t_cap):
+        w.step(engine, step)
+        step += 1
+    w.drain_end = time.perf_counter()
+
+
+def ttft_p90_s(w) -> tuple[float, int]:
+    """90th percentile of due-to-first-token times, and the number of
+    requests that never got one (each ranked above every served one)."""
+    served = [w.first[r] - w.due[r] for r in w.due if r in w.first]
+    unserved = len(w.due) - len(served)
+    worst = w.drain_end - min(w.due.values())
+    return float(np.percentile(served + [worst] * unserved, 90)), unserved
 
 
 def metrics(w):
@@ -194,7 +240,7 @@ def metrics(w):
     return out, stats
 
 
-def window_flops(w, m):
+def window_flops(w, m, layer_flops=None):
     """Model FLOPs of the work that the window's steps finished."""
     from bench import flops as F
 
@@ -206,9 +252,11 @@ def window_flops(w, m):
             if not (w.t0 <= t <= w.window_end):
                 continue
             if i == 0:
-                total += F.prefill_flops(m, 0, p, last_chunk=True)
+                total += F.prefill_flops(m, 0, p, last_chunk=True,
+                                         layer_flops=layer_flops)
             else:
-                total += F.decode_flops(m, p + i - 1)
+                total += F.decode_flops(m, p + i - 1,
+                                        layer_flops=layer_flops)
     return total
 
 
@@ -263,16 +311,25 @@ def run(run):
     w = drive(engine, arrivals, run)
     e2e, stats = metrics(w)
     stats["slowest_steps"] = run.slowest([(ts, te, cpu) for ts, te, _, _, cpu
-                                          in w.steps], w.t0)
+                                          in w.steps if te <= w.window_end],
+                                         w.t0)
     stats["warm_requests"] = warmed
+    failed = len(w.refused)
+    if "drain_s" in run.traffic:
+        drain(engine, w, run.traffic["drain_s"])
+        e2e["ttft_p90_s"], unserved = ttft_p90_s(w)
+        failed = unserved
+        stats["drain_s"] = w.drain_end - w.window_end
+        stats["unserved"] = unserved
     run.memory_peak()
-    run.flops_in_window = window_flops(w, run.model)
+    ref = run.reference()
+    run.flops_in_window = window_flops(w, run.model,
+                                       getattr(ref, "layer_flops", None))
     del engine
     gc.collect()
 
     t_check = time.perf_counter()
     sample = check_sample(w, run.traffic, run.seed)
-    ref = run.reference()
     gap, n_tok = logit_gaps(ref, params, run.model, w, sample, None)
     stats["check_requests"] = len(sample)
     stats["check_tokens"] = n_tok
@@ -286,6 +343,6 @@ def run(run):
              for i, s in enumerate(w.steps)}
     return {"e2e": e2e, "stats": stats, "checks": checks,
             "controls": controls,
-            "attempted": len(w.due), "failed": len(w.refused),
+            "attempted": len(w.due), "failed": failed,
             "ok": bool(sample) and n_tok > 0,
             "step_kinds": kinds}

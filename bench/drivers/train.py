@@ -81,7 +81,7 @@ def build(run, mesh):
                       out_shardings=(pshard, oshard, None),
                       donate_argnums=(0, 1))
     params = make_params(pshapes, seed=run.seed, d_model=cfg.d_model,
-                         sharding=pshard)
+                         init=run.config.get("init"), sharding=pshard)
     opt_state = jax.device_put(init_opt(params), oshard)
     loader = DataLoader(DataConfig(vocab=cfg.vocab,
                                    global_batch=t["global_batch"],
@@ -163,7 +163,8 @@ def _run(run, mesh):
                         "loss_wait_ms": [s[3] - s[2] for s in steps]})}
     run.memory_peak()
     run.flops_in_window = n_steps * F.train_step_flops(
-        run.model, batch=t["global_batch"], seq_len=t["seq_len"])
+        run.model, batch=t["global_batch"], seq_len=t["seq_len"],
+        layer_flops=getattr(run.reference(), "layer_flops", None))
     del params, opt_state, out, batch
     gc.collect()
 

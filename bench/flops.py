@@ -19,6 +19,13 @@ count does not change with what implements it:
 - backward costs twice the forward; recomputation is not counted;
 - bytes are the inputs and outputs of a call, at their dtype's size.
 
+Layers are counted by the configuration's ``layer_pattern`` (default
+``["global"]``), layer ``i`` being of kind ``pattern[i % len(pattern)]``.
+This module counts ``global`` layers (attention and a dense MLP). A layer
+of any other kind is counted by ``layer_flops(m, kind, n_past, n_new)``
+of the configuration's plain reference, where it defines one, and
+otherwise the count raises: no kind is counted as another.
+
 Elementwise work (norms, activations, the Taylor polynomial) is left
 out: it is small against the counted terms and no bound rests on it.
 """
@@ -60,36 +67,66 @@ def dense_params_per_layer(m: dict) -> int:
     return attn + mlp
 
 
-def lm_flops(m: dict, *, tokens: int, attn: int, logits: int) -> int:
+COUNTED = ("global",)
+
+
+def layer_kinds(m: dict) -> list[str]:
+    """The kind of each of the model's layers, in order."""
+    pattern = m.get("layer_pattern", ("global",))
+    return [pattern[i % len(pattern)] for i in range(m["n_layers"])]
+
+
+def lm_flops(m: dict, *, tokens: int, attn: int, logits: int,
+             other=None) -> int:
     """Forward FLOPs of the whole model for ``tokens`` positions with
-    ``attn`` attention FLOPs per layer and ``logits`` read-out rows."""
-    return (2 * tokens * dense_params_per_layer(m) * m["n_layers"]
-            + attn * m["n_layers"]
-            + 2 * logits * m["d_model"] * m["vocab"])
+    ``attn`` attention FLOPs per ``global`` layer and ``logits`` read-out
+    rows. ``other(kind)`` gives a layer of another kind for the same
+    work; without it such a layer raises."""
+    total = 2 * logits * m["d_model"] * m["vocab"]
+    for kind in layer_kinds(m):
+        if kind in COUNTED:
+            total += 2 * tokens * dense_params_per_layer(m) + attn
+        elif other is not None:
+            total += other(kind)
+        else:
+            raise ValueError(
+                f"no FLOP count for layer kind {kind!r}: the "
+                "configuration's reference defines no layer_flops")
+    return total
 
 
-def train_step_flops(m: dict, *, batch: int, seq_len: int) -> int:
+def _other(m: dict, layer_flops, n_past: int, n_new: int, times: int = 1):
+    if layer_flops is None:
+        return None
+    return lambda kind: times * layer_flops(m, kind, n_past, n_new)
+
+
+def train_step_flops(m: dict, *, batch: int, seq_len: int,
+                     layer_flops=None) -> int:
     """Forward and backward of one optimizer step of a non-causal
     encoder trained with a loss at every position."""
     attn = batch * attn_flops_noncausal(seq_len, m["head_dim"], m["n_heads"],
                                         m["n_kv_heads"])
     tokens = batch * seq_len
-    return 3 * lm_flops(m, tokens=tokens, attn=attn, logits=tokens)
+    return 3 * lm_flops(m, tokens=tokens, attn=attn, logits=tokens,
+                        other=_other(m, layer_flops, 0, seq_len, batch))
 
 
 def prefill_flops(m: dict, n_past: int, n_new: int, *,
-                  last_chunk: bool) -> int:
+                  last_chunk: bool, layer_flops=None) -> int:
     """One prompt chunk of one request; a logit only after the last."""
     attn = attn_flops_causal(n_past, n_new, m["head_dim"], m["n_heads"],
                              m["n_kv_heads"])
-    return lm_flops(m, tokens=n_new, attn=attn, logits=int(last_chunk))
+    return lm_flops(m, tokens=n_new, attn=attn, logits=int(last_chunk),
+                    other=_other(m, layer_flops, n_past, n_new))
 
 
-def decode_flops(m: dict, n_past: int) -> int:
+def decode_flops(m: dict, n_past: int, layer_flops=None) -> int:
     """One decoded token of one request after ``n_past`` absorbed ones."""
     attn = attn_flops_causal(n_past, 1, m["head_dim"], m["n_heads"],
                              m["n_kv_heads"])
-    return lm_flops(m, tokens=1, attn=attn, logits=1)
+    return lm_flops(m, tokens=1, attn=attn, logits=1,
+                    other=_other(m, layer_flops, n_past, 1))
 
 
 def efficient_attention_call(n: int, d: int, *, heads: int,

@@ -14,6 +14,26 @@ window, the slowest steps), the result as one JSON object on the last
 line of standard output, and each number that decides ``correct``
 beside its limit as the last lines of standard error.
 
+What a configuration brings (``bench/configs/<config>.json``):
+
+- ``model``: the keyword arguments of the program's ``ModelConfig``,
+  with ``taylor``, ``moe`` and ``ssm`` as nested sections of
+  ``TaylorConfig``, ``MoEConfig`` and ``SSMConfig``; an unknown key
+  raises;
+- ``init`` (optional): a rule for each parameter leaf whose name the
+  built-in table of ``bench/weights.py`` does not know, keyed by that
+  name (``{"A_log": {"rule": "log_linspace", "lo": 1, "hi": 16}}``);
+- ``optimizer``, for training cells;
+- ``reference``: the name of its plain reference,
+  ``bench/configs/<reference>.py``, which imports nothing of the
+  program and defines ``logits_at(params, m, tokens, positions, *,
+  rounding)`` for serving cells, ``train_steps(params, m, opt, batches,
+  *, stored, rounding)`` for training cells, and, where the model has a
+  layer kind that ``bench/flops.py`` does not count (anything but
+  ``global`` attention with its MLP), ``layer_flops(m, kind, n_past,
+  n_new)``: the forward FLOPs of one such layer for ``n_new`` tokens
+  after ``n_past`` of one sequence. ``rounding="fp8"`` is the control.
+
 With ``--calibrate 1`` the driver also returns the same numbers read
 from the control and from each planted fault, each put in the program's
 place. Each goes through the comparison that decides ``correct``, and
@@ -30,7 +50,7 @@ import json
 import shutil
 import sys
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
@@ -82,11 +102,15 @@ class Tracer:
         self.active = False
         self._ann = None
 
-    def maybe_start(self, elapsed: float) -> None:
+    def maybe_start(self, elapsed: float, settle=None) -> None:
+        """Start tracing once ``elapsed`` reaches the traced part, after
+        ``settle()``, a wait for the device, where given."""
         if not self.enabled or self.active or elapsed < self.start_at:
             return
         import jax
 
+        if settle is not None:
+            settle()
         shutil.rmtree(self.log_dir, ignore_errors=True)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
@@ -104,6 +128,17 @@ class Tracer:
         jax.profiler.stop_trace()
         self.active = False
         self.enabled = False
+
+
+NESTED = {"taylor": "TaylorConfig", "moe": "MoEConfig", "ssm": "SSMConfig"}
+
+
+def _dataclass(cls, values: dict, where: str):
+    unknown = set(values) - {f.name for f in fields(cls)}
+    if unknown:
+        raise ValueError(f"configuration section {where!r} has keys "
+                         f"{sorted(unknown)} that {cls.__name__} does not")
+    return cls(**values)
 
 
 @dataclass
@@ -135,11 +170,19 @@ class Run:
         return self.config["model"]
 
     def program_config(self):
-        """The program's ModelConfig with the configuration's sizes."""
-        from repro.configs.base import ModelConfig
+        """The program's ModelConfig with the configuration's sizes; the
+        nested ``taylor``, ``moe`` and ``ssm`` sections become their
+        dataclasses. A key the program does not have raises."""
+        from repro.configs import base
 
         m = dict(self.model)
-        return ModelConfig(name=self.config["name"], **m)
+        for key, cls in NESTED.items():
+            if key in m:
+                m[key] = _dataclass(getattr(base, cls), m[key], key)
+        if "layer_pattern" in m:
+            m["layer_pattern"] = tuple(m["layer_pattern"])
+        return _dataclass(base.ModelConfig, dict(m, name=self.config["name"]),
+                          "model")
 
     def reference(self):
         return load_module(self.base / "configs" /

@@ -4,10 +4,11 @@ The least time that the forward and backward attention of every step in
 the traced window needs (``bench/flops.py``: FLOPs at the bf16 peak or
 bytes at the HBM peak, whichever is longer) over the summed device time
 of the Pallas kernels of ``kernels/taylor_efficient.py`` and
-``kernels/taylor_grad.py`` inside those steps. The kernels carry no name
-of their own in the trace yet, so every ``tpu_custom_call`` of the
-train step counts: in this cell those are only the efficient-form
-attention kernels and their backward.
+``kernels/taylor_grad.py`` inside those steps. Every ``tpu_custom_call``
+of the train step counts: in this cell those are only the efficient-form
+attention kernels, named ``taylor_eff_fwd_*`` and ``taylor_eff_bwd_*``
+in the trace (``breakdown.device_ops`` shows them), the same set a match
+on those names would select.
 """
 
 from bench import flops as F

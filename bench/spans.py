@@ -16,7 +16,8 @@ from bench import trace_reduce as TR
 
 def by_thread(path: str, window: tuple[float, float]) -> list[list]:
     """Host events wholly inside ``window`` that are not the harness's
-    ``bench.*`` annotations, one list per host thread, in start order."""
+    ``bench.*`` annotations, with their arguments, one list per host
+    thread, in start order."""
     from jax.profiler import ProfileData
 
     lo, hi = window
@@ -25,7 +26,8 @@ def by_thread(path: str, window: tuple[float, float]) -> list[list]:
         if not plane.name.startswith("/host:"):
             continue
         for line in plane.lines:
-            events = [TR.Event(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            events = [TR.Event(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                               args=tuple(dict(e.stats).items()))
                       for e in line.events
                       if not e.name.startswith("bench.")
                       and lo <= e.start_ns

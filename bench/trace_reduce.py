@@ -45,6 +45,7 @@ class Event:
     start: float      # ns on the profiler's clock
     end: float
     step: int | None = None   # a host annotation's ``step`` argument
+    args: tuple = ()          # a program span's arguments, (name, value)
 
 
 @dataclass

@@ -21,9 +21,16 @@ Parameters of a serving mix::
      "engine": {...},
      "check": {"min_tokens": 300, "max_requests": 24}}
 
-``dist`` is ``"exponential"`` (``mean``), ``"lognormal"`` (``median``,
-``sigma``) or ``"uniform"`` (between ``min`` and ``max``); every length
-is clamped to ``[min, max]``.
+``dist`` is ``"exponential"`` (``mean``, or ``median``: the mean is then
+median / ln 2), ``"lognormal"`` (``median``, ``sigma``) or ``"uniform"``
+(between ``min`` and ``max``); every length is clamped to ``[min, max]``.
+
+A mix with ``"drain_s"`` is judged on its time to first token: after
+the window the engine steps on, with no new submissions, until every
+request due in the window has its first token or ``drain_s`` seconds
+have passed. A mix with ``"settle_trace": true`` is traced between two
+waits for the device, so that the traced part holds the device time of
+every program dispatched in it (``prefill_ms_per_1k_tokens`` needs it).
 """
 
 from __future__ import annotations
@@ -57,7 +64,8 @@ def quantiles(spec: dict, n: int) -> np.ndarray:
     if kind == "uniform":
         x = spec["min"] + u * (spec["max"] - spec["min"])
     elif kind == "exponential":
-        x = -spec["mean"] * np.log1p(-u)
+        mean = spec["mean"] if "mean" in spec else spec["median"] / math.log(2)
+        x = -mean * np.log1p(-u)
     elif kind == "lognormal":
         z = np.array([NormalDist().inv_cdf(float(p)) for p in u])
         x = spec["median"] * np.exp(spec["sigma"] * z)

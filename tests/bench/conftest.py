@@ -1,7 +1,9 @@
 """Fixtures for the benchmark's CPU tests: a tiny copy of the benchmark.
 
 ``tiny_bench`` writes a BENCHMARK.json with one small training cell and
-one small serving cell, their configurations, mixes and limits, and
+two small serving cells (the second judged on its time to first token,
+with the drain after the window), their configurations, mixes and
+limits, and
 copies the real plain reference and metric readers, all under
 ``tmp_path``. The harness runs such a copy exactly as it runs the real
 one, with the look for a chip skipped.
@@ -66,6 +68,20 @@ def make_tiny_bench(root: Path, *, train_limits=None, serve_limits=None):
            "engine": {"n_slots": 2, "cache_kind": "taylor",
                       "prefill_chunk": 16, "token_budget": 32},
            "check": {"min_tokens": 20, "max_requests": 4}})
+    write(base / "traffic" / "tiny-prefill.json",
+          {"kind": "serve",
+           "arrival": {"process": "poisson", "rate_per_s": 3.0},
+           "prompt_tokens": {"dist": "exponential", "median": 40,
+                             "min": 1, "max": 96},
+           "output_tokens": {"dist": "exponential", "median": 3,
+                             "min": 1, "max": 8},
+           "schedule_seed": 0,
+           "engine": {"n_slots": 2, "cache_kind": "taylor",
+                      "prefill_chunk": 16, "token_budget": 32},
+           "check": {"min_tokens": 12, "max_requests": 6},
+           "drain_s": 60, "settle_trace": True})
+    write(base / "limits" / "tiny-prefill.json", serve_limits or
+          {"logit_gap": 1e-3})
     write(base / "limits" / "tiny-train.json", train_limits or
           {"loss_gap": 1e-3, "grad_gap": 1e-2, "update_gap": 1e-2})
     write(base / "limits" / "tiny-serve.json", serve_limits or
@@ -88,19 +104,24 @@ def make_tiny_bench(root: Path, *, train_limits=None, serve_limits=None):
             {"name": "tiny-train", "config": "tiny-lra",
              "traffic": "tiny-train", "chips": 1, "why": "test"},
             {"name": "tiny-serve", "config": "tiny-lm",
-             "traffic": "tiny-serve", "chips": 1, "why": "test"}],
+             "traffic": "tiny-serve", "chips": 1, "why": "test"},
+            {"name": "tiny-prefill", "config": "tiny-lm",
+             "traffic": "tiny-prefill", "chips": 1, "why": "test"}],
         "end_to_end": [
             e2e("train_tokens_per_s", "tokens/s", "higher", ["tiny-train"]),
             e2e("serve_gen_tokens_per_s", "tokens/s", "higher",
                 ["tiny-serve"]),
-            e2e("itl_p95_ms", "ms", "lower", ["tiny-serve"]),
+            e2e("itl_p95_ms", "ms", "lower", ["tiny-serve", "tiny-prefill"]),
+            e2e("ttft_p90_s", "s", "lower", ["tiny-prefill"]),
             {"name": "setup_s", "unit": "s", "better": "lower",
              "bound": 0.25, "source": "host_clock"}],
         "per_layer": [
             layer("mfu.train", "%", "model step", "train_tokens_per_s",
                   "tiny-train"),
             layer("decode_step_ms", "ms", "engine", "serve_gen_tokens_per_s",
-                  "tiny-serve")],
+                  "tiny-serve"),
+            layer("prefill_ms_per_1k_tokens", "ms", "model step",
+                  "ttft_p90_s", "tiny-prefill")],
     }
     write(root / "BENCHMARK.json", bench)
     return root / "BENCHMARK.json"

@@ -16,7 +16,7 @@ LOGNORMAL = {
                       "min": 1024, "max": 4032},
     "output_tokens": {"dist": "uniform", "min": 8, "max": 32},
     "schedule_seed": 0}
-MIXES = ["lmsys-chat", "lognormal"]
+MIXES = ["lmsys-chat", "azure-code", "lognormal"]
 
 
 def mix(name):
@@ -102,3 +102,17 @@ def test_exponential_lengths_keep_the_published_mean():
     q = G.quantiles(spec, 2000)
     assert abs(q.mean() - 214.5) / 214.5 < 0.01
     assert q.min() >= 1 and np.all(np.diff(q) >= 0)
+
+
+def test_exponential_lengths_keep_the_published_median():
+    spec = {"dist": "exponential", "median": 1500, "min": 1, "max": 10**6}
+    q = G.quantiles(spec, 2001)
+    assert q[1000] == 1500
+    assert abs(q.mean() - 1500 / np.log(2)) / (1500 / np.log(2)) < 0.01
+
+
+def test_long_prompt_mix_fits_the_context():
+    # prompts are clamped to stablelm-2's 4096 positions less the
+    # longest output the mix can ask for
+    m = mix("azure-code")
+    assert G.longest_request(m) == 4096
