@@ -15,6 +15,7 @@ _ARCH_MODULES = {
     "gemma2-27b": "gemma2_27b",
     "llava-next-34b": "llava_next_34b",
     "zamba2-7b": "zamba2_7b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
     "llama4-maverick-400b-a17b": "llama4_maverick",
     "grok-1-314b": "grok_1",
     "xlstm-125m": "xlstm_125m",

@@ -38,6 +38,9 @@ class SSMConfig:
     conv_width: int = 4
     n_groups: int = 1             # B/C groups (GVA-style)
     chunk: int = 64
+    conv_bias: bool = False       # bias on the causal conv (mamba_conv_bias)
+    mlp: bool = False             # a gated-MLP sub-layer after each `mamba`
+    #   mixer (granite-4.0-h); zamba2's blocks have none
 
 
 @dataclass(frozen=True)
@@ -111,6 +114,7 @@ class ModelConfig:
     act: str = "silu"
     gated_mlp: bool = True
     norm: str = "rms"             # rms | ln
+    rms_norm_eps: float = 1e-6    # eps of every RMSNorm, the gated one too
     post_norm: bool = False       # gemma2-style post-block norms
     qk_norm: bool = False
     # --- attention ---------------------------------------------------------
@@ -127,6 +131,10 @@ class ModelConfig:
     pos_embed: str = "rope"       # rope | learned | none
     max_seq_len: int = 8192       # for learned positions only
     tie_embeddings: bool = True
+    # --- muP multipliers (granite) -------------------------------------------
+    embedding_multiplier: float | None = None  # None = sqrt(d_model)
+    residual_multiplier: float = 1.0   # scales every sub-layer's output
+    logits_divisor: float = 1.0        # logits = readout / logits_divisor
     # --- MoE / SSM ----------------------------------------------------------
     moe: MoEConfig = field(default_factory=MoEConfig)
     ssm: SSMConfig = field(default_factory=SSMConfig)

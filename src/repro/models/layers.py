@@ -81,9 +81,9 @@ def layernorm(params: Params, x: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
     return (y * params["scale"] + params["bias"]).astype(x.dtype)
 
 
-def make_norm(kind: str):
+def make_norm(kind: str, rms_eps: float = 1e-6):
     if kind == "rms":
-        return rmsnorm_init, rmsnorm
+        return rmsnorm_init, lambda p, x: rmsnorm(p, x, rms_eps)
     if kind == "ln":
         return layernorm_init, layernorm
     raise ValueError(kind)

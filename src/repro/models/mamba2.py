@@ -7,7 +7,10 @@ attention); the block is implemented faithfully as the substrate the
 hybrid architecture needs (docs/design.md §Arch-applicability).
 
 Decode: constant-size per-layer state — causal-conv tail (width-1 window)
-plus the SSM state h ∈ (B, H, P, S).
+plus the SSM state h ∈ (B, H, S, P). Chunked prefill
+(:func:`mamba2_prefill`) starts from that state and returns it, so a
+prompt absorbed in chunks of any length reaches the state one pass over
+the whole prompt would.
 """
 
 from __future__ import annotations
@@ -47,6 +50,8 @@ def mamba2_init(key, cfg: ModelConfig) -> Params:
         "dt_bias": jnp.zeros((H,), jnp.float32),
         "norm": L.rmsnorm_init(d_inner),
     }
+    if s.conv_bias:
+        p["conv_b"] = jnp.zeros((d_inner + 2 * G * S,), dt)
     return p
 
 
@@ -57,18 +62,34 @@ def _split_in_proj(cfg, zxbcdt):
     return z, xbc, dt_raw
 
 
-def _causal_conv(w, x):
-    """Depthwise causal conv, width W. x: (B, N, C); w: (W, C)."""
+def _causal_conv(params, x, tail):
+    """Depthwise causal conv of width W (plus its bias, where the block has
+    one), then SiLU. x: (B, N, C); tail: (B, W-1, C), the inputs before
+    x (zeros at a sequence's start). Returns (out, the new tail)."""
+    w = params["conv_w"]
     W = w.shape[0]
-    pad = jnp.pad(x, ((0, 0), (W - 1, 0), (0, 0)))
-    out = sum(pad[:, i:i + x.shape[1]] * w[i][None, None] for i in range(W))
-    return jax.nn.silu(out.astype(jnp.float32)).astype(x.dtype)
+    full = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    out = sum(full[:, i:i + x.shape[1]] * w[i][None, None] for i in range(W))
+    if "conv_b" in params:
+        out = out + params["conv_b"]
+    return (jax.nn.silu(out.astype(jnp.float32)).astype(x.dtype),
+            full[:, full.shape[1] - (W - 1):])
 
 
-def _ssd_chunked(xh, dt, A, Bm, Cm, chunk):
-    """SSD core. xh: (B,N,H,P), dt: (B,N,H), A: (H,), Bm/Cm: (B,N,G,S).
+def _gated_norm(params, cfg: ModelConfig, y, z, dtype):
+    """Mamba2's gated RMSNorm: the gate before the norm,
+    rmsnorm(y · silu(z)), as in the reference Mamba2 and HF's
+    ``MambaRMSNormGated``."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    return L.rmsnorm(params["norm"], g, cfg.rms_norm_eps).astype(dtype)
 
-    Returns y: (B,N,H,P). G divides H (heads share B/C within a group).
+
+def _ssd_chunked(xh, dt, A, Bm, Cm, chunk, h0=None):
+    """SSD core. xh: (B,N,H,P), dt: (B,N,H), A: (H,), Bm/Cm: (B,N,G,S);
+    h0: (B,H,S,P) state before the first token (zeros where None).
+
+    Returns (y (B,N,H,P), the state after the last token). G divides H
+    (heads share B/C within a group).
     """
     b, n, h, p = xh.shape
     g = Bm.shape[2]
@@ -114,8 +135,9 @@ def _ssd_chunked(xh, dt, A, Bm, Cm, chunk):
         hnew = hprev * dec[..., None, None] + st
         return hnew, hprev
 
-    h0 = jnp.zeros((b, h, Bh.shape[-1], p), jnp.float32)
-    _, h_prefix = jax.lax.scan(
+    if h0 is None:
+        h0 = jnp.zeros((b, h, Bh.shape[-1], p), jnp.float32)
+    h_last, h_prefix = jax.lax.scan(
         scan_fn, h0,
         (states.transpose(1, 0, 2, 3, 4), chunk_decay.transpose(1, 0, 2)))
     h_prefix = h_prefix.transpose(1, 0, 2, 3, 4)         # (B,nc,H,S,P) excl.
@@ -124,17 +146,17 @@ def _ssd_chunked(xh, dt, A, Bm, Cm, chunk):
     y_inter = jnp.einsum("bzihs,bzih,bzhsp->bzihp",
                          C_c, jnp.exp(cs), h_prefix)
     y = (y_intra + y_inter).reshape(b, n, h, p)
-    return y
+    return y, h_last
 
 
-def mamba2_apply(params: Params, cfg: ModelConfig, x: jnp.ndarray
-                 ) -> jnp.ndarray:
-    """x: (B, N, d_model) -> (B, N, d_model)."""
+def _mixer(params: Params, cfg: ModelConfig, x, conv_tail, h0):
+    """The block over (B, N, d_model) from a conv tail and SSM state.
+    Returns (y, new conv tail, new state)."""
     s = cfg.ssm
     d_inner, H, P, S, G = _dims(cfg)
     zxbcdt = L.dense(params["in_proj"], x)
     z, xbc, dt_raw = _split_in_proj(cfg, zxbcdt)
-    xbc = _causal_conv(params["conv_w"], xbc)
+    xbc, tail = _causal_conv(params, xbc, conv_tail)
     xs, Bm, Cm = jnp.split(xbc, [d_inner, d_inner + G * S], axis=-1)
     b, n, _ = x.shape
     xh = xs.reshape(b, n, H, P)
@@ -146,11 +168,27 @@ def mamba2_apply(params: Params, cfg: ModelConfig, x: jnp.ndarray
     chunk = min(s.chunk, n)
     while n % chunk:
         chunk //= 2
-    y = _ssd_chunked(xh, dt, A, Bm, Cm, max(chunk, 1))
+    y, h = _ssd_chunked(xh, dt, A, Bm, Cm, max(chunk, 1), h0)
     y = y + params["D"][None, None, :, None] * xh.astype(jnp.float32)
-    y = y.reshape(b, n, d_inner).astype(x.dtype)
-    y = L.rmsnorm(params["norm"], y) * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
-    return L.dense(params["out_proj"], y)
+    y = _gated_norm(params, cfg, y.reshape(b, n, d_inner), z, x.dtype)
+    return L.dense(params["out_proj"], y), tail, h
+
+
+def mamba2_apply(params: Params, cfg: ModelConfig, x: jnp.ndarray
+                 ) -> jnp.ndarray:
+    """x: (B, N, d_model) -> (B, N, d_model), from the zero state."""
+    d_inner, _, _, S, G = _dims(cfg)
+    tail = jnp.zeros((x.shape[0], cfg.ssm.conv_width - 1,
+                      d_inner + 2 * G * S), x.dtype)
+    return _mixer(params, cfg, x, tail, None)[0]
+
+
+def mamba2_prefill(params: Params, cfg: ModelConfig, x: jnp.ndarray, cache):
+    """Absorb a (B, C, d_model) chunk into a decode cache (the conv tail
+    and the SSM state every row carries): the chunked-prefill site.
+    The SSD chunk is ``min(ssm.chunk, C)``. Returns (y, new cache)."""
+    y, tail, h = _mixer(params, cfg, x, cache["conv"], cache["h"])
+    return y, {"conv": tail.astype(cache["conv"].dtype), "h": h}
 
 
 # ---------------------------------------------------------------------------
@@ -176,6 +214,8 @@ def mamba2_decode(params: Params, cfg: ModelConfig, x: jnp.ndarray, cache):
     w = params["conv_w"]
     W = w.shape[0]
     out = jnp.sum(conv_in[:, -W:] * w[None], axis=1, keepdims=True)
+    if "conv_b" in params:
+        out = out + params["conv_b"]
     xbc = jax.nn.silu(out.astype(jnp.float32)).astype(x.dtype)
     new_conv = conv_in[:, 1:]
 
@@ -192,6 +232,5 @@ def mamba2_decode(params: Params, cfg: ModelConfig, x: jnp.ndarray, cache):
                          xh.astype(jnp.float32), dt))
     y = jnp.einsum("bhs,bhsp->bhp", Cm.astype(jnp.float32), hnew)
     y = y + params["D"][None, :, None] * xh.astype(jnp.float32)
-    y = y.reshape(b, 1, d_inner).astype(x.dtype)
-    y = L.rmsnorm(params["norm"], y) * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
+    y = _gated_norm(params, cfg, y.reshape(b, 1, d_inner), z, x.dtype)
     return L.dense(params["out_proj"], y), {"conv": new_conv, "h": hnew}

@@ -41,8 +41,26 @@ ATTN_KINDS = ("global", "local", "global_moe")
 # Block init / apply / decode — dispatch on pattern kind
 # ---------------------------------------------------------------------------
 
+def _norm(cfg: ModelConfig):
+    """(init, apply) of the configured norm, at the configured RMS eps."""
+    return L.make_norm(cfg.norm, cfg.rms_norm_eps)
+
+
+def _ffn_init(kind: str, key, cfg: ModelConfig, ninit) -> Params:
+    """Params of the MLP (or MoE) sub-layer after a block's mixer."""
+    p: Params = {"norm2": ninit(cfg.d_model)}
+    if kind == "global_moe":
+        p["moe"] = MOE.moe_init(key, cfg)
+    else:
+        p["mlp"] = L.mlp_init(key, cfg.d_model, cfg.d_ff,
+                              gated=cfg.gated_mlp, dtype=cfg.param_dtype)
+    if cfg.post_norm:
+        p["norm2_post"] = ninit(cfg.d_model)
+    return p
+
+
 def _block_init(kind: str, key, cfg: ModelConfig, *, cross: bool = False) -> Params:
-    ninit, _ = L.make_norm(cfg.norm)
+    ninit, _ = _norm(cfg)
     ks = jax.random.split(key, 4)
     p: Params = {}
     if kind in ATTN_KINDS:
@@ -54,23 +72,16 @@ def _block_init(kind: str, key, cfg: ModelConfig, *, cross: bool = False) -> Par
             p["norm_x"] = ninit(cfg.d_model)
             p["cross"] = A.attn_init(ks[3], cfg)
         if cfg.d_ff:
-            p["norm2"] = ninit(cfg.d_model)
-            if kind == "global_moe":
-                p["moe"] = MOE.moe_init(ks[1], cfg)
-            else:
-                p["mlp"] = L.mlp_init(ks[1], cfg.d_model, cfg.d_ff,
-                                      gated=cfg.gated_mlp,
-                                      dtype=cfg.param_dtype)
-            if cfg.post_norm:
-                p["norm2_post"] = ninit(cfg.d_model)
-    elif kind == "mamba":
+            p.update(_ffn_init(kind, ks[1], cfg, ninit))
+    elif kind in ("mamba", "mamba_shared"):
+        if kind == "mamba_shared":
+            # shared attention weights live at top level; only norms
+            # are local
+            p["norm_shared"] = ninit(cfg.d_model)
         p["norm1"] = ninit(cfg.d_model)
         p["mamba"] = M2.mamba2_init(ks[0], cfg)
-    elif kind == "mamba_shared":
-        # shared attention weights live at top level; only norms are local
-        p["norm_shared"] = ninit(cfg.d_model)
-        p["norm1"] = ninit(cfg.d_model)
-        p["mamba"] = M2.mamba2_init(ks[0], cfg)
+        if cfg.ssm.mlp:
+            p.update(_ffn_init(kind, ks[1], cfg, ninit))
     elif kind == "mlstm":
         p["norm1"] = ninit(cfg.d_model)
         p["mlstm"] = XL.mlstm_init(ks[0], cfg)
@@ -89,7 +100,7 @@ def _block_apply(kind: str, p: Params, cfg: ModelConfig, x, *, positions,
     carry x stays d_model-sharded; each sub-layer's input is explicitly
     all-gathered in bf16 (ctx.gathered) and its output reduce-scattered
     back (ctx.activations)."""
-    _, norm = L.make_norm(cfg.norm)
+    _, norm = _norm(cfg)
     aux = jnp.zeros((), jnp.float32)
     if kind in ATTN_KINDS:
         z = ctx.gathered(norm(p["norm1"], x))
@@ -99,39 +110,60 @@ def _block_apply(kind: str, p: Params, cfg: ModelConfig, x, *, positions,
         h = ctx.activations(h)
         if cfg.post_norm:
             h = norm(p["norm1_post"], h)
-        x = x + h
+        x = _residual(cfg, x, h)
         if cross_kv is not None:
             h = A.attn_apply(p["cross"], cfg,
                              ctx.gathered(norm(p["norm_x"], x)),
                              positions=positions, cross_kv=cross_kv)
-            x = x + ctx.activations(h)
-        if cfg.d_ff:
-            z = ctx.gathered(norm(p["norm2"], x))
-            if kind == "global_moe":
-                h, aux = MOE.moe_apply(p["moe"], cfg, z)
-            else:
-                h = L.mlp(p["mlp"], z, act=cfg.act)
-            h = ctx.activations(h)
-            if cfg.post_norm:
-                h = norm(p["norm2_post"], h)
-            x = x + h
+            x = _residual(cfg, x, ctx.activations(h))
     elif kind in ("mamba", "mamba_shared"):
         if kind == "mamba_shared":
             assert shared is not None
             h = A.attn_apply(shared["attn"], cfg,
                              ctx.gathered(norm(p["norm_shared"], x)),
                              positions=positions, causal=causal)
-            x = x + ctx.activations(h)
+            x = _residual(cfg, x, ctx.activations(h))
         h = M2.mamba2_apply(p["mamba"], cfg,
                             ctx.gathered(norm(p["norm1"], x)))
-        x = x + ctx.activations(h)
+        x = _residual(cfg, x, ctx.activations(h))
     elif kind == "mlstm":
         h = XL.mlstm_apply(p["mlstm"], cfg, ctx.gathered(norm(p["norm1"], x)))
-        x = x + ctx.activations(h)
+        x = _residual(cfg, x, ctx.activations(h))
     elif kind == "slstm":
         h = XL.slstm_apply(p["slstm"], cfg, ctx.gathered(norm(p["norm1"], x)))
-        x = x + ctx.activations(h)
+        x = _residual(cfg, x, ctx.activations(h))
+    if "norm2" in p:
+        x, aux = _ffn(kind, p, cfg, x, norm, seq_parallel=True)
     return x, aux
+
+
+def _residual(cfg: ModelConfig, x, h):
+    """x + h, the sub-layer's output scaled by the residual multiplier
+    (skipped at 1, so those models' programs are unchanged)."""
+    if cfg.residual_multiplier == 1.0:
+        return x + h
+    return x + h * jnp.asarray(cfg.residual_multiplier, h.dtype)
+
+
+def _ffn(kind: str, p: Params, cfg: ModelConfig, x, norm, *,
+         seq_parallel: bool = False):
+    """The MLP (or MoE) sub-layer after a block's mixer, with its
+    residual: shared by the full-sequence, decode and chunk-absorb
+    paths. ``seq_parallel`` puts in the full-sequence path's explicit
+    gather and scatter (see :func:`_block_apply`). Returns (x, aux)."""
+    aux = jnp.zeros((), jnp.float32)
+    z = norm(p["norm2"], x)
+    if seq_parallel:
+        z = ctx.gathered(z)
+    if kind == "global_moe":
+        h, aux = MOE.moe_apply(p["moe"], cfg, z)
+    else:
+        h = L.mlp(p["mlp"], z, act=cfg.act)
+    if seq_parallel:
+        h = ctx.activations(h)
+    if cfg.post_norm:
+        h = norm(p["norm2_post"], h)
+    return _residual(cfg, x, h), aux
 
 
 def _block_init_cache(kind: str, cfg: ModelConfig, batch: int, cache_len: int,
@@ -168,7 +200,7 @@ def _cache_kind_for(kind: str, cfg: ModelConfig, cache_kind: str, batch: int,
 
 def _block_decode(kind: str, p: Params, cfg: ModelConfig, x, cache, *,
                   shared: Params | None, cross_state=None):
-    _, norm = L.make_norm(cfg.norm)
+    _, norm = _norm(cfg)
     if kind in ATTN_KINDS or kind == "local":
         akind = "local" if kind == "local" else "global"
         h, cache_a = A.attn_decode(
@@ -176,73 +208,65 @@ def _block_decode(kind: str, p: Params, cfg: ModelConfig, x, cache, *,
             cache["self"] if cross_state is not None else cache, kind=akind)
         if cfg.post_norm:
             h = norm(p["norm1_post"], h)
-        x = x + h
+        x = _residual(cfg, x, h)
         if cross_state is not None:
             h, _ = A.attn_decode(p["cross"], cfg, norm(p["norm_x"], x), None,
                                  cross_state=cross_state)
-            x = x + h
+            x = _residual(cfg, x, h)
             cache = {"self": cache_a}
         else:
             cache = cache_a
-        if cfg.d_ff:
-            z = norm(p["norm2"], x)
-            if kind == "global_moe":
-                h, _ = MOE.moe_apply(p["moe"], cfg, z)
-            else:
-                h = L.mlp(p["mlp"], z, act=cfg.act)
-            if cfg.post_norm:
-                h = norm(p["norm2_post"], h)
-            x = x + h
     elif kind in ("mamba", "mamba_shared"):
         if kind == "mamba_shared":
             h, ca = A.attn_decode(shared["attn"], cfg,
                                   norm(p["norm_shared"], x), cache["attn"])
-            x = x + h
+            x = _residual(cfg, x, h)
             y, cm = M2.mamba2_decode(p["mamba"], cfg, norm(p["norm1"], x),
                                      cache["mamba"])
-            x = x + y
+            x = _residual(cfg, x, y)
             cache = {"attn": ca, "mamba": cm}
         else:
             y, cache = M2.mamba2_decode(p["mamba"], cfg, norm(p["norm1"], x),
                                         cache)
-            x = x + y
+            x = _residual(cfg, x, y)
     elif kind == "mlstm":
         y, cache = XL.mlstm_decode(p["mlstm"], cfg, norm(p["norm1"], x), cache)
-        x = x + y
+        x = _residual(cfg, x, y)
     elif kind == "slstm":
         y, cache = XL.slstm_decode(p["slstm"], cfg, norm(p["norm1"], x), cache)
-        x = x + y
+        x = _residual(cfg, x, y)
+    if "norm2" in p:
+        x, _ = _ffn(kind, p, cfg, x, norm)
     return x, cache
 
 
-PREFILL_KINDS = ("global", "global_moe")
+PREFILL_KINDS = ("global", "global_moe", "mamba")
 
 
 def _block_absorb(kind: str, p: Params, cfg: ModelConfig, x, cache, *,
                   attend, what: str):
     """One residual block over a multi-token chunk that consumes and
     returns the decode cache — shared body of chunked prefill
-    (``attend=attn_prefill``, per-sequence scalar counters) and
-    speculative verify (``attend=attn_verify``, per-slot counters; the
-    whole pool in one call). Global-attention kinds only: local
-    ring-buffer windows and SSM/xLSTM blocks would need their own
-    chunkwise state handoff."""
+    (``attend=attn_prefill``, per-sequence scalar counters) and the
+    per-slot body of pooled prefill and speculative verify
+    (``attend=attn_verify``, per-slot counters; the whole pool in one
+    call). Global attention, and Mamba2, whose conv tail and SSM state
+    carry no counter, so both bodies run the same chunked SSD from the
+    cached state. Local ring-buffer windows, the shared-attention
+    hybrid and xLSTM blocks would need their own chunkwise handoff."""
     if kind not in PREFILL_KINDS:
         raise NotImplementedError(f"{what}: unsupported block kind {kind!r}")
-    _, norm = L.make_norm(cfg.norm)
-    h, cache = attend(p["attn"], cfg, norm(p["norm1"], x), cache)
-    if cfg.post_norm:
-        h = norm(p["norm1_post"], h)
-    x = x + h
-    if cfg.d_ff:
-        z = norm(p["norm2"], x)
-        if kind == "global_moe":
-            h, _ = MOE.moe_apply(p["moe"], cfg, z)
-        else:
-            h = L.mlp(p["mlp"], z, act=cfg.act)
+    _, norm = _norm(cfg)
+    if kind == "mamba":
+        h, cache = M2.mamba2_prefill(p["mamba"], cfg, norm(p["norm1"], x),
+                                     cache)
+    else:
+        h, cache = attend(p["attn"], cfg, norm(p["norm1"], x), cache)
         if cfg.post_norm:
-            h = norm(p["norm2_post"], h)
-        x = x + h
+            h = norm(p["norm1_post"], h)
+    x = _residual(cfg, x, h)
+    if "norm2" in p:
+        x, _ = _ffn(kind, p, cfg, x, norm)
     return x, cache
 
 
@@ -276,7 +300,7 @@ def init_params(cfg: ModelConfig, rng) -> Params:
     keys = jax.random.split(rng, 8)
     p: Params = {"embed": L.embedding_init(keys[0], cfg.vocab, cfg.d_model,
                                            cfg.param_dtype)}
-    ninit, _ = L.make_norm(cfg.norm)
+    ninit, _ = _norm(cfg)
     p["final_norm"] = ninit(cfg.d_model)
     if not cfg.tie_embeddings:
         p["unembed"] = L.dense_init(keys[6], cfg.d_model, cfg.vocab,
@@ -325,11 +349,18 @@ def init_params(cfg: ModelConfig, rng) -> Params:
 # Forward (train / full-sequence)
 # ---------------------------------------------------------------------------
 
+def _embed_tokens(params, cfg: ModelConfig, tokens):
+    """Token embedding times the embedding multiplier (sqrt(d_model)
+    unless the configuration sets one)."""
+    x = L.embed(params["embed"], tokens)
+    mult = (jnp.sqrt(cfg.d_model) if cfg.embedding_multiplier is None
+            else cfg.embedding_multiplier)
+    return x * jnp.asarray(mult, cfg.param_dtype)
+
+
 def _embed_inputs(params, cfg: ModelConfig, batch):
     """Token (+ frontend-stub) embedding. Returns (x, positions)."""
-    tokens = batch["tokens"]
-    x = L.embed(params["embed"], tokens) * jnp.asarray(
-        jnp.sqrt(cfg.d_model), cfg.param_dtype)
+    x = _embed_tokens(params, cfg, batch["tokens"])
     if cfg.frontend == "vision_stub" and "patch_embeds" in batch:
         x = jnp.concatenate([batch["patch_embeds"].astype(x.dtype), x], axis=1)
     positions = jnp.arange(x.shape[1])
@@ -385,13 +416,13 @@ def _encode(params, cfg: ModelConfig, frames):
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
     x, _ = jax.lax.scan(body_fn, x, params["enc"]["blocks"])
-    _, norm = L.make_norm(cfg.norm)
+    _, norm = _norm(cfg)
     return norm(params["enc"]["final_norm"], x)
 
 
 def forward(params, cfg: ModelConfig, batch, *, training: bool = False):
     """Returns (hidden (B,N,d), aux_loss). N includes any stub prefix."""
-    _, norm = L.make_norm(cfg.norm)
+    _, norm = _norm(cfg)
     if cfg.family == "encdec":
         enc_out = _encode(params, cfg, batch["frames"])
         x, positions = _embed_inputs(params, cfg, batch)
@@ -418,6 +449,8 @@ def logits_from_hidden(params, cfg: ModelConfig, hidden):
         lg = L.unembed(params["embed"], hidden)
     else:
         lg = L.dense(params["unembed"], hidden).astype(jnp.float32)
+    if cfg.logits_divisor != 1.0:
+        lg = lg / cfg.logits_divisor
     if cfg.softcap_final:
         lg = L.softcap(lg, cfg.softcap_final)
     return lg
@@ -527,10 +560,8 @@ def decode_step(params, cfg: ModelConfig, batch, cache):
 
     batch: {"tokens": (B, 1)}. Returns (logits (B,1,V), new cache).
     """
-    _, norm = L.make_norm(cfg.norm)
-    tokens = batch["tokens"]
-    x = L.embed(params["embed"], tokens) * jnp.asarray(
-        jnp.sqrt(cfg.d_model), cfg.param_dtype)
+    _, norm = _norm(cfg)
+    x = _embed_tokens(params, cfg, batch["tokens"])
     if cfg.pos_embed == "learned":
         p = cache["pos"]
         x = L.add_learned_pos(params["pos"], x,
@@ -593,11 +624,10 @@ def _chunk_apply(params, cfg: ModelConfig, batch, cache, block_fn,
     scalar (per-sequence) or per-slot (B,)."""
     if cfg.family == "encdec":
         raise NotImplementedError(f"{what}: decoder families only")
-    _, norm = L.make_norm(cfg.norm)
+    _, norm = _norm(cfg)
     tokens = batch["tokens"]
     C = tokens.shape[1]
-    x = L.embed(params["embed"], tokens) * jnp.asarray(
-        jnp.sqrt(cfg.d_model), cfg.param_dtype)
+    x = _embed_tokens(params, cfg, tokens)
     if cfg.pos_embed == "learned":
         p = cache["pos"]
         step = jnp.arange(C)

@@ -131,8 +131,20 @@ class Engine:
         bad = [k for k in cfg.layer_pattern if k not in PREFILL_KINDS]
         if bad or cfg.family == "encdec":
             raise NotImplementedError(
-                "serve engine: chunked prefill supports global-attention "
-                f"decoder architectures (pattern {tuple(cfg.layer_pattern)})")
+                "serve engine: chunked prefill supports decoder "
+                f"architectures of {PREFILL_KINDS} layers (pattern "
+                f"{tuple(cfg.layer_pattern)})")
+        if "mamba" in cfg.layer_pattern:
+            # an SSM state is a running product, not a sum of per-token
+            # terms: nothing takes drafted tokens back out of it
+            if econf.speculate_k > 0:
+                raise ValueError(
+                    "speculate_k > 0 with Mamba2 layers: verify's rollback "
+                    "cannot un-absorb SSM state")
+            if econf.prefix_cache_mb:
+                raise ValueError(
+                    "prefix_cache_mb != 0 with Mamba2 layers: the prefix "
+                    "cache keeps no entries of SSM state")
         self.cfg = cfg
         self.econf = econf
         self.replica_id = econf.replica_id
@@ -152,10 +164,19 @@ class Engine:
         self.pool = StatePool(cfg, econf.n_slots,
                               cache_len=cache_len,
                               cache_kind=self.plan.cache_kind)
+        # recurrent state each dispatched program reads, as span args:
+        # the whole pool (decode, pooled prefill) or one slot's share
+        self._state_by_kind = by_kind = self.pool.state_bytes()
+        self._pool_state = {
+            "ssm_state_bytes": by_kind["ssm"] + by_kind["conv"],
+            "taylor_state_bytes": by_kind["taylor"]}
+        self._slot_state = {k: v // econf.n_slots
+                            for k, v in self._pool_state.items()}
         self.queue = AdmissionQueue(econf.max_queue)
         self.stats = EngineStats()
         self.scheduler = Scheduler(econf.token_budget)
         self.scheduler.bind_registry(self.stats.registry)
+        self._publish_pool_state()
         # shared-prefix state cache: entries are immutable snapshots of
         # the chunked-prefill cache at full-chunk boundaries, so a hit
         # is a zero-copy resume (serve/prefix_cache.py). Keyed on the
@@ -309,6 +330,7 @@ class Engine:
         never change emitted tokens."""
         self.stats = EngineStats()
         self.scheduler.bind_registry(self.stats.registry)
+        self._publish_pool_state()
         if self.prefix_cache is not None:
             self.stats.prefix_cache_baseline = self.prefix_cache.stats()
         if self._controller is not None:
@@ -316,6 +338,14 @@ class Engine:
             self._controller = DraftController(self.econf.speculate_k,
                                                self.econf.spec,
                                                registry=self.stats.registry)
+
+    def _publish_pool_state(self) -> None:
+        g = self.stats.registry.gauge(
+            "serve_pool_state_bytes",
+            "bytes of the slot pool's recurrent state, by kind",
+            labelnames=("kind",))
+        for kind, n in self._state_by_kind.items():
+            g.labels(kind=kind).set(n)
 
     def render_metrics(self) -> str:
         """Prometheus text exposition over every registry the engine
@@ -584,7 +614,7 @@ class Engine:
                 dec_span = tracer.span(
                     "decode_batch",
                     compile_key=("decode", self.pool.n_slots),
-                    slots=len(plan.decode))
+                    slots=len(plan.decode), **self._pool_state)
                 if tracer.enabled:
                     # batched phases list every member request so the
                     # per-request timeline can claim them; guarded so
@@ -641,7 +671,8 @@ class Engine:
                         with tracer.span(
                                 "prefill_chunk",
                                 compile_key=("prefill", c),
-                                request=s.request_id, chunk=c):
+                                request=s.request_id, chunk=c,
+                                **self._slot_state):
                             prefill_tokens += PF.advance_prefill(
                                 s, self._prefill_fn, self.prefix_cache)
                         budget -= c
@@ -662,7 +693,9 @@ class Engine:
                         compile_key=(("prefill_pool", c)
                                      if len(group) > 1
                                      else ("prefill_slot", c)),
-                        slots=len(group), chunk=c)
+                        slots=len(group), chunk=c,
+                        **(self._pool_state if len(group) > 1
+                           else self._slot_state))
                     if tracer.enabled:
                         grp_span.set("requests",
                                      [s.request_id for s in group])
@@ -692,7 +725,8 @@ class Engine:
                         with tracer.span(
                                 "prefill_chunk",
                                 compile_key=("prefill", c),
-                                request=s.request_id, chunk=c):
+                                request=s.request_id, chunk=c,
+                                **self._slot_state):
                             prefill_tokens += PF.advance_prefill(
                                 s, self._prefill_fn, self.prefix_cache)
                         budget -= c

@@ -1,4 +1,5 @@
-"""Slot-based TaylorState cache pool.
+"""Slot-based decode-state pool (Taylor states, and Mamba2 state beside
+them in a hybrid).
 
 Preallocates the model's whole decode cache with a leading slot
 dimension — for a TaylorShift model that is
@@ -32,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core import taylor as T
 from repro.models import model as M
 
 
@@ -129,3 +131,26 @@ class StatePool:
     def nbytes(self) -> int:
         from repro.serve.prefix_cache import tree_nbytes
         return tree_nbytes(self.cache)
+
+    def state_bytes(self) -> dict[str, int]:
+        """Bytes of the pool's recurrent state by kind: ``ssm`` (Mamba2's
+        state h), ``conv`` (its conv tails) and ``taylor`` (the Taylor
+        states of the attention layers). A decode step reads all of it."""
+        from repro.serve.prefix_cache import tree_nbytes
+        out = {"ssm": 0, "conv": 0, "taylor": 0}
+
+        def walk(t):
+            if isinstance(t, T.TaylorState):
+                out["taylor"] += tree_nbytes(t)
+            elif isinstance(t, dict) and set(t) == {"conv", "h"}:
+                out["conv"] += tree_nbytes(t["conv"])
+                out["ssm"] += tree_nbytes(t["h"])
+            elif isinstance(t, dict):
+                for v in t.values():
+                    walk(v)
+            elif isinstance(t, (list, tuple)):
+                for v in t:
+                    walk(v)
+
+        walk(self.cache)
+        return out

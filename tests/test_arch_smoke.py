@@ -97,6 +97,7 @@ class TestFullConfigMetadata:
             "gemma2-27b": (24e9, 30e9),
             "llava-next-34b": (30e9, 38e9),
             "zamba2-7b": (6e9, 8.5e9),
+            "granite-4.0-h-micro": (3.0e9, 3.4e9),
             "llama4-maverick-400b-a17b": (360e9, 440e9),
             "grok-1-314b": (290e9, 340e9),
             "xlstm-125m": (0.9e8, 1.6e8),

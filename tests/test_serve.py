@@ -126,14 +126,51 @@ def test_staggered_arrivals_match_solo_runs(setup):
 
 
 def test_engine_rejects_unsupported_patterns():
-    """Local-window (ring cache) and SSM blocks have no chunked-prefill
-    state handoff yet: the engine must refuse them up front rather than
-    silently prefilling their windows as global context."""
+    """Local-window (ring cache) and shared-attention SSM blocks have no
+    chunked-prefill state handoff yet: the engine must refuse them up
+    front rather than silently prefilling their windows as global
+    context."""
     for arch in ("gemma3-1b", "zamba2-7b"):
         cfg = get_config(arch).reduced()
         params = M.init_params(cfg, jax.random.PRNGKey(0))
         with pytest.raises(NotImplementedError):
             Engine(cfg, params, EngineConfig(n_slots=1, max_seq_len=64))
+
+
+@pytest.fixture(scope="module")
+def granite():
+    cfg = get_config("granite-4.0-h-micro").reduced()
+    return cfg, M.init_params(cfg, jax.random.PRNGKey(0))
+
+
+def test_engine_serves_mamba2_hybrid(granite):
+    """granite's pattern (Mamba2 and global attention) is served, and each
+    span reports the recurrent state its program reads."""
+    cfg, params = granite
+    eng = Engine(cfg, params, EngineConfig(n_slots=2, prefill_chunk=8,
+                                           max_seq_len=64))
+    out = eng.generate([Request("a", _prompt(cfg, 13, 1), max_new_tokens=3)])
+    assert len(out["a"]) == 3
+    by_kind = eng.pool.state_bytes()
+    assert by_kind["ssm"] and by_kind["conv"] and by_kind["taylor"]
+    assert eng._pool_state["ssm_state_bytes"] == (by_kind["ssm"]
+                                                  + by_kind["conv"])
+    assert eng._slot_state["taylor_state_bytes"] * 2 == by_kind["taylor"]
+    gauge = eng.stats.registry.get("serve_pool_state_bytes")
+    assert {c.labels["kind"]: c.value for c in gauge.children} == by_kind
+
+
+@pytest.mark.parametrize("knob,value", [("speculate_k", 2),
+                                        ("prefix_cache_mb", 1.0)])
+def test_engine_refuses_rollback_and_prefix_reuse_with_ssm(granite, knob,
+                                                           value):
+    """Verify's rollback cannot take drafted tokens back out of an SSM
+    state, and the prefix cache keeps no SSM state: both are refused at
+    construction, not discovered mid-stream."""
+    cfg, params = granite
+    with pytest.raises(ValueError, match="Mamba2"):
+        Engine(cfg, params, EngineConfig(n_slots=1, max_seq_len=64,
+                                         **{knob: value}))
 
 
 def test_admission_backpressure(setup):
