@@ -52,7 +52,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.taylor_direct import taylor_direct_attention
-from repro.kernels.taylor_efficient import (_amod_call, _pick_chunk_factor,
+from repro.kernels.taylor_efficient import (_amod_call, _boxtimes_t,
+                                            _pick_chunk_factor,
                                             _readout_call, build_vhat)
 
 
@@ -298,8 +299,7 @@ def _sym_quad_kernel(xt_ref, u0t_ref, u1t_ref, a0_ref, a1_ref, o_ref, acc, *,
             a0_ref[0], xt, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    ut = u1t_ref[0]                                      # (cf, bq)
-    pt = (ut[:, None, :] * xt[None, :, :]).reshape(cf * d, xt.shape[1])
+    pt = _boxtimes_t(u1t_ref[0], xt, cf, d)              # (cf·d, bq)
     acc[...] += jax.lax.dot_general(a1_ref[0], pt, (((1,), (0,)), ((), ())),
                                     preferred_element_type=jnp.float32)
 
@@ -310,20 +310,21 @@ def _sym_quad_kernel(xt_ref, u0t_ref, u1t_ref, a0_ref, a1_ref, o_ref, acc, *,
 
 def _sym_quad_call(x, u, a_mod, *, cf: int, block_q: int, interpret: bool,
                    name: str):
-    """(BH, N, d), (BH, N, d+1), (BH, d², d+1) -> (BH, N, d):
+    """(BH, N, d), (BH, N, d+1), Aᵀ (BH, d+1, d²) -> (BH, N, d):
     (M_i + M_iᵀ) x_i for an A symmetric in its row pair (see the kernel).
+    Aᵀ is laid out as ``_amod_call`` returns it.
 
     The operands are laid out for the kernel in XLA: x and u transposed
-    (queries in lanes), and A's value column f moved from lanes into the
+    (queries in lanes), and A's value column f moved into the
     contraction, next to b. Each is one pass over an (N, d) or (d², d)
     array, against the kernel's N·d³ multiply-adds.
     """
     bh, n, d = x.shape
     xt = jnp.swapaxes(x, 1, 2).astype(jnp.float32)       # (BH, d, N)
     ut = jnp.swapaxes(u, 1, 2).astype(jnp.float32)       # (BH, d+1, N)
-    rows = a_mod.reshape(bh, d, d, d + 1)                # [a, b, f]
-    a0 = rows[..., 0]                                    # (BH, d, d)
-    a1 = jnp.swapaxes(rows[..., 1:], 2, 3).reshape(bh, d, d * d)
+    a0 = a_mod[:, 0].reshape(bh, d, d)                   # [a, b]
+    a1 = jnp.swapaxes(a_mod[:, 1:].reshape(bh, d, d, d), 1, 2)  # [a, f, b]
+    a1 = a1.reshape(bh, d, d * d)
     ot = pl.pallas_call(
         functools.partial(_sym_quad_kernel, cf=cf, d=d),
         grid=(bh, n // block_q, d // cf),

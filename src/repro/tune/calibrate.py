@@ -151,6 +151,10 @@ def sweep_kernel_blocks(d: int, *, n: int = 256, reps: int = 3,
     for bq, bk in candidates:
         if n % min(bq, n) or n % min(bk, n):
             continue
+        # compiled, the efficient kernels hold a token block in lanes:
+        # a multiple of 128, or all of N
+        if not interpret and any(b < n and b % 128 for b in (bq, bk)):
+            continue
         t = _time_best(
             lambda q, k, v: taylor_direct_attention(
                 q, k, v, block_q=bq, block_k=bk, interpret=interpret),

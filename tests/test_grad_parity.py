@@ -282,7 +282,7 @@ class TestSymQuadKernel:
         out = _sym_quad_call(x, u, a, cf=_pick_chunk_factor(d), block_q=16,
                              interpret=True,
                              name=f"taylor_eff_bwd_symquad_{site}")
-        m = jnp.einsum("zef,znf->zne", a, u,
+        m = jnp.einsum("zfe,znf->zne", a, u,
                        precision="highest").reshape(2, n, d, d)
         want = jnp.einsum("znab,znb->zna", m + jnp.swapaxes(m, -1, -2), x,
                           precision="highest")
@@ -295,10 +295,11 @@ class TestSymQuadKernel:
     @pytest.mark.parametrize("site", ["dq", "dk"])
     def test_amod_rows_symmetric(self, site, d):
         """The kernel's precondition: A_mod(k, v̂) and amod(q, ĝ) equal
-        their π(a, b) ↔ π(b, a) row permutation bit for bit."""
+        their π(a, b) ↔ π(b, a) row permutation bit for bit (``_amod_call``
+        returns Aᵀ, so the pair indexes its columns)."""
         _, _, a = _sym_quad_operands(site, 64, d, 61)
-        rows = a.reshape(2, d, d, d + 1)
-        assert bool(jnp.all(rows == jnp.swapaxes(rows, 1, 2)))
+        cols = a.reshape(2, d + 1, d, d)
+        assert bool(jnp.all(cols == jnp.swapaxes(cols, 2, 3)))
 
 
 # ---------------------------------------------------------------------------

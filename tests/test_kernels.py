@@ -11,7 +11,9 @@ pytestmark = pytest.mark.kernels
 from repro.core import taylor as T
 from repro.kernels import ops
 from repro.kernels import ref
-from repro.kernels.taylor_efficient import _pick_chunk_factor
+from repro.kernels.taylor_efficient import (_amod_call, _lane_block,
+                                            _pick_chunk_factor,
+                                            _readout_call, build_vhat)
 
 
 def rand(key, b, h, n, d, dtype=jnp.float32):
@@ -101,21 +103,58 @@ class TestEfficientKernel:
         np.testing.assert_allclose(np.asarray(yd), np.asarray(ye),
                                    rtol=3e-4, atol=3e-4)
 
-    def test_amod_phase(self):
-        """Phase A in isolation against the ⊠-product oracle."""
-        from repro.kernels.taylor_efficient import _amod_call
-        d = 16
-        key = jax.random.PRNGKey(5)
-        k = jax.random.normal(key, (3, 64, d))
-        v = jax.random.normal(jax.random.fold_in(key, 1), (3, 64, d))
-        ones = jnp.ones((3, 64, 1), jnp.float32)
-        vh = jnp.concatenate([ones, v], axis=-1)
-        cf = _pick_chunk_factor(d)
-        a = _amod_call(k, vh, cf=cf, block_k=32, interpret=True,
-                       name="taylor_eff_fwd_amod")
-        a_ref = ref.amod_ref(k, v)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(a_ref),
-                                   rtol=1e-4, atol=1e-4)
+    @staticmethod
+    def _phase_operands(d, n, m_valid):
+        """α-scaled normalized q, k as ops.py prepares them, keys past
+        ``m_valid`` zero-padded, V̂ built from the first ``m_valid``."""
+        q, k, v = rand(jax.random.PRNGKey(d + m_valid), 1, 3, n, d)
+        qs, ks = ops._prep(q, k, 1.0)
+        qs, ks = qs[0], ks[0] * (jnp.arange(n) < m_valid)[None, :, None]
+        return q[0], k[0], v[0], qs, ks, build_vhat(v[0], m_valid)
+
+    @pytest.mark.parametrize("d", [8, 16, 32])
+    @pytest.mark.parametrize("n,m_valid", [(64, 64), (64, 61)])
+    def test_amod_phase(self, d, n, m_valid):
+        """Phase A in isolation against the ⊠-product oracle; the kernel
+        returns A_modᵀ, (BH, d+1, d²)."""
+        _, _, v, _, ks, vh = self._phase_operands(d, n, m_valid)
+        a = _amod_call(ks, vh, cf=_pick_chunk_factor(d), block_k=32,
+                       interpret=True, name="taylor_eff_fwd_amod")
+        assert a.shape == (3, d + 1, d * d)
+        a_ref = ref.amod_ref(ks[:, :m_valid], v[:, :m_valid])
+        np.testing.assert_allclose(np.asarray(jnp.swapaxes(a, 1, 2)),
+                                   np.asarray(a_ref), rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("d", [8, 16, 32])
+    @pytest.mark.parametrize("n,m_valid", [(64, 64), (64, 61)])
+    @pytest.mark.parametrize("form", ["divided", "raw"])
+    def test_readout_phase(self, form, d, n, m_valid):
+        """Phase B in isolation, on A_modᵀ from phase A: divided with the
+        output scale, as the forward entry runs it, against Algorithm 1;
+        raw with coefs (1, 1, 1), as the dV̂ backward runs it, against
+        the bilinear form at HIGHEST."""
+        q, k, v, qs, ks, vh = self._phase_operands(d, n, m_valid)
+        a = _amod_call(ks, vh, cf=_pick_chunk_factor(d), block_k=32,
+                       interpret=True, name="taylor_eff_fwd_amod")
+        kv = jnp.einsum("bnd,bnf->bdf", ks, vh)
+        s0 = jnp.sum(vh, axis=1, keepdims=True)
+        divide = form == "divided"
+        y = _readout_call(qs, a, kv, s0, cf=_pick_chunk_factor(d),
+                          block_q=32, n_keys=m_valid, out_scale=divide,
+                          out_dtype=jnp.float32, interpret=True,
+                          name="taylor_eff_fwd_readout",
+                          coefs=None if divide else (1.0, 1.0, 1.0),
+                          divide=divide)
+        if divide:
+            want = T.efficient_taylorshift(q, k[:, :m_valid], v[:, :m_valid])
+            tol = dict(rtol=3e-4, atol=3e-4)
+        else:
+            hi = dict(precision="highest")
+            want = (jnp.einsum("bne,bfe->bnf", T.boxtimes(qs, qs), a, **hi)
+                    + jnp.einsum("bnd,bdf->bnf", qs, kv, **hi) + s0)
+            tol = dict(rtol=1e-5, atol=1e-5 * float(jnp.max(jnp.abs(want))))
+        assert y.shape == want.shape
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want), **tol)
 
     @pytest.mark.parametrize("d,budget,expect_fit", [
         (64, 8 << 20, True), (128, 8 << 20, True), (256, 8 << 20, True),
@@ -132,11 +171,21 @@ class TestKernelVmemFootprint:
     @pytest.mark.parametrize("d", [64, 128, 144, 256, 288])
     def test_efficient_tiles_fit(self, d):
         cf = _pick_chunk_factor(d)
-        block_k = 128
+        block_k = _lane_block(8192, 128, d, cf)
         tile = cf * d * (d + 1) * 4           # A_mod accumulator
         k2 = block_k * cf * d * 4             # expanded K chunk
         inputs = block_k * (2 * d + 1) * 4
         assert tile + k2 + inputs < 15 * 1024 * 1024, (d, cf)
+
+    @pytest.mark.parametrize("n,block,d,want", [
+        (8192, 128, 64, 512),   # the training cell: (4096, 512) fp32 ⊠ operand
+        (640, 128, 64, 128),    # 256 does not divide N
+        (8192, 256, 32, 512),   # never past 512
+        (8192, 128, 128, 128),  # (8192, 256) operand beside the tile: over
+        (64, 32, 8, 32),        # interpret mode's small blocks stay
+    ])
+    def test_lane_block(self, n, block, d, want):
+        assert _lane_block(n, block, d, _pick_chunk_factor(d)) == want
 
     @pytest.mark.parametrize("d", [64, 128, 256])
     def test_direct_tiles_fit(self, d):

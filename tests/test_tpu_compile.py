@@ -27,7 +27,8 @@ import pytest
 from repro.core import taylor as T
 from repro.kernels.taylor_decode import taylor_decode_kernel
 from repro.kernels.taylor_direct import taylor_direct_attention
-from repro.kernels.taylor_efficient import (_pick_chunk_factor,
+from repro.kernels.taylor_efficient import (_amod_call, _pick_chunk_factor,
+                                            _readout_call,
                                             taylor_efficient_attention)
 from repro.kernels.taylor_grad import (_sym_quad_call,
                                        taylor_direct_attention_vjp,
@@ -149,7 +150,36 @@ def test_sym_quad_kernel_compiles(one_chip, site):
                                        block_q=128, interpret=False,
                                        name=name),
         _spec((BH, N, D), one_chip), _spec((BH, N, D + 1), one_chip),
-        _spec((BH, D * D, D + 1), one_chip))
+        _spec((BH, D + 1, D * D), one_chip))
+    assert _kernels(text) == [name]
+
+
+@pytest.mark.parametrize("name", ["taylor_eff_fwd_amod",
+                                  "taylor_eff_bwd_amod_da"])
+def test_amod_kernel_compiles(one_chip, name):
+    """Phase A alone: the ⊠ product of x with itself built in VMEM with
+    tokens in lanes, contracted with ûᵀ over the lanes of both."""
+    text = _compiled_text(
+        lambda x, u: _amod_call(x, u, cf=_pick_chunk_factor(D), block_k=128,
+                                interpret=False, name=name),
+        _spec((BH, N, D), one_chip), _spec((BH, N, D + 1), one_chip))
+    assert _kernels(text) == [name]
+
+
+@pytest.mark.parametrize("name,divide", [("taylor_eff_fwd_readout", True),
+                                         ("taylor_eff_fwd_readout", False),
+                                         ("taylor_eff_bwd_readout_dv", False)])
+def test_readout_kernel_compiles(one_chip, name, divide):
+    """Phase B alone, divided (the forward entry) and raw (the custom-VJP
+    forward and the dV̂ backward): A_modᵀ against the ⊠ product of the
+    queries, queries in lanes."""
+    text = _compiled_text(
+        lambda x, a, kv, s0: _readout_call(
+            x, a, kv, s0, cf=_pick_chunk_factor(D), block_q=128, n_keys=N,
+            out_scale=divide, out_dtype=jnp.float32, interpret=False,
+            name=name, divide=divide),
+        _spec((BH, N, D), one_chip), _spec((BH, D + 1, D * D), one_chip),
+        _spec((BH, D, D + 1), one_chip), _spec((BH, 1, D + 1), one_chip))
     assert _kernels(text) == [name]
 
 

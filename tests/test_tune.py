@@ -156,3 +156,17 @@ def test_kernel_blocks_defaults_and_overrides(clean_install):
     assert TU.kernel_blocks(64) == (128, 128)          # no entry at all
     TU.uninstall()
     assert TU.kernel_blocks(16) == (128, 128)
+
+
+def test_block_sweep_compiled_times_lane_aligned_blocks_only(monkeypatch):
+    """Compiled, the efficient kernels hold a token block in lanes, so
+    the sweep times only blocks that are a multiple of 128 or all of N."""
+    import importlib
+    from repro.kernels import ops
+    C = importlib.import_module("repro.tune.calibrate")
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    timed = []
+    monkeypatch.setattr(C, "_time_best",
+                        lambda fn, *a, reps: timed.append(fn) or 1.0)
+    assert C.sweep_kernel_blocks(16, n=256) == (128, 128)
+    assert len(timed) == 2                     # direct + efficient, once
